@@ -82,6 +82,17 @@ def backend_key() -> str:
     return plat if plat == "tpu" else f"{plat}-interpret"
 
 
+def _decode_attn_snap(kvh: int, max_pages: int, psz: int, ppb: int,
+                      hb: int) -> tuple[int, int]:
+    """The (ppb, hb) the decode kernel will actually run for a request:
+    Mosaic-legal blocks on TPU (`sa_decode_attention.tpu_blocks`), plain
+    divisor clipping in interpret mode."""
+    from .sa_decode_attention import largest_divisor, tpu_blocks
+    if backend_key() == "tpu":
+        return tpu_blocks(max_pages, kvh, psz, ppb, hb)
+    return largest_divisor(max_pages, ppb), largest_divisor(kvh, hb)
+
+
 def production_dtype() -> str:
     """The dtype `sa_dot` actually hands the kernel on this backend: f32
     containers on CPU (`precision.EXACT_CPU_CONTAINERS`), bf16 on TPU.
@@ -115,11 +126,22 @@ def _read_disk() -> dict:
         return {}
 
 
+def _sweep_requested() -> bool:
+    return os.environ.get("REPRO_AUTOTUNE", "0") not in ("0", "false", "off")
+
+
 def _load_disk_once():
+    """Merge the on-disk cache into `_MEM` once per process — only when
+    tuning was asked for (REPRO_AUTOTUNE) or a cache file was named
+    (REPRO_AUTOTUNE_CACHE). By default nothing is read from disk and an
+    unseen shape takes the MXU heuristic, so a run's blocks never depend on
+    a cache file left behind by an earlier sweep."""
     global _DISK_LOADED
     if _DISK_LOADED:
         return
     _DISK_LOADED = True
+    if not (_sweep_requested() or "REPRO_AUTOTUNE_CACHE" in os.environ):
+        return
     for key, ent in _read_disk().items():
         try:
             blocks = tuple(int(x) for x in ent["blocks"])
@@ -280,8 +302,7 @@ def lookup(m: int, n: int, k: int, *, dtype: str = "bfloat16",
     if hit is not None:
         return hit
     if sweep is None:
-        sweep = os.environ.get("REPRO_AUTOTUNE", "0") not in ("0", "false",
-                                                              "off")
+        sweep = _sweep_requested()
     if sweep and _trace_state_clean():
         return tune(m, n, k, dtype=dtype, epilogue=epilogue)[0]
     # heuristic fallback — deliberately NOT memoized, so a later in-process
@@ -313,24 +334,22 @@ def decode_attn_key(batch: int, kvh: int, g: int, hd: int, psz: int,
             f"{psz}x{max_pages}|{dtype}")
 
 
-def default_decode_attn_blocks(kvh: int, max_pages: int) -> tuple[int, int]:
-    """Heuristic: walk up to 8 pages per grid step, one KV head."""
-    from .sa_decode_attention import largest_divisor
-    return largest_divisor(max_pages, 8), 1
+def default_decode_attn_blocks(kvh: int, max_pages: int,
+                               psz: int) -> tuple[int, int]:
+    """Heuristic: walk up to 8 pages per grid step, one KV head (snapped to
+    the smallest legal tiling on TPU)."""
+    return _decode_attn_snap(kvh, max_pages, psz, 8, 1)
 
 
-def decode_attn_candidates(kvh: int, max_pages: int
+def decode_attn_candidates(kvh: int, max_pages: int, psz: int
                            ) -> list[tuple[int, int]]:
-    from .sa_decode_attention import largest_divisor
     # (max_pages, kvh) collapses the page/head axes into a single grid
     # step — the interpret-mode winner (no while-loop carry copies) and a
     # legitimate TPU shape for small pools
-    pool = DECODE_ATTN_CANDIDATES + (
-        (max_pages, 1), (max_pages, kvh),
-        default_decode_attn_blocks(kvh, max_pages))
+    pool = DECODE_ATTN_CANDIDATES + ((max_pages, 1), (max_pages, kvh))
     seen, out = set(), []
-    for ppb, hb in pool:
-        c = (largest_divisor(max_pages, ppb), largest_divisor(kvh, hb))
+    for ppb, hb in pool + (default_decode_attn_blocks(kvh, max_pages, psz),):
+        c = _decode_attn_snap(kvh, max_pages, psz, ppb, hb)
         if c not in seen:
             seen.add(c)
             out.append(c)
@@ -385,7 +404,7 @@ def tune_decode_attn(batch: int, kvh: int, g: int, hd: int, psz: int,
         return (time.perf_counter() - t0) / reps * 1e6
 
     table = [{"blocks": c, "us": time_one(*c)}
-             for c in decode_attn_candidates(kvh, max_pages)]
+             for c in decode_attn_candidates(kvh, max_pages, psz)]
     table.sort(key=lambda r: r["us"])
     best = tuple(table[0]["blocks"])
     key = decode_attn_key(batch, kvh, g, hd, psz, max_pages, dtype)
@@ -406,9 +425,8 @@ def lookup_decode_attn(batch: int, kvh: int, g: int, hd: int, psz: int,
     if hit is not None and len(hit) == 2:
         return hit
     if sweep is None:
-        sweep = os.environ.get("REPRO_AUTOTUNE", "0") not in ("0", "false",
-                                                              "off")
+        sweep = _sweep_requested()
     if sweep and _trace_state_clean():
         return tune_decode_attn(batch, kvh, g, hd, psz, max_pages,
                                 dtype=dtype)[0]
-    return default_decode_attn_blocks(kvh, max_pages)
+    return default_decode_attn_blocks(kvh, max_pages, psz)

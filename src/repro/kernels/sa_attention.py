@@ -21,12 +21,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pltpu_compat import CompilerParams as _CompilerParams
 
 
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                  n_kv: int, bq: int, bkv: int, scale: float, causal: bool,
-                 window: int, cap: float, q_offset: int):
+                 window: int, cap: float, q_offset: int, kv_len: int):
     jkv = pl.program_id(3)
     iq = pl.program_id(2)
 
@@ -45,7 +44,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     q_pos = q_offset + iq * bq + jax.lax.broadcasted_iota(jnp.int32,
                                                           (bq, bkv), 0)
     kv_pos = jkv * bkv + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1)
-    ok = jnp.ones((bq, bkv), jnp.bool_)
+    # kv rows past kv_len are the wrapper's block padding
+    ok = kv_pos < kv_len
     if causal:
         ok &= q_pos >= kv_pos
     if window:
@@ -70,6 +70,15 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                     jnp.maximum(l_ref[...], 1e-30)[:, None]).astype(o_ref.dtype)
 
 
+def _blocks(n: int, cap: int, unit: int) -> tuple[int, int]:
+    """(block, padded length) for an axis of length n: the fewest blocks of
+    at most ~cap rows, each rounded up to `unit` (the Mosaic tile)."""
+    nb = -(-n // max(1, cap))
+    b = -(-n // nb)
+    b = -(-b // unit) * unit
+    return b, nb * b
+
+
 @functools.partial(
     jax.jit, static_argnames=("causal", "window", "cap", "q_offset", "bq",
                               "bkv", "interpret"))
@@ -77,23 +86,30 @@ def sa_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                  causal: bool = True, window: int = 0, cap: float = 0.0,
                  q_offset: int = 0, bq: int = 512, bkv: int = 512,
                  interpret: bool = False) -> jax.Array:
-    """q: (B, H, T, hd); k, v: (B, KVH, S, hd) → (B, H, T, hd)."""
+    """q: (B, H, T, hd); k, v: (B, KVH, S, hd) → (B, H, T, hd) fp32.
+
+    `bq`/`bkv` cap the block rows. Any T and S are served: the axes are
+    split into the fewest blocks of at most that size and zero-padded to a
+    whole number of blocks (on TPU each block rounded to the Mosaic tile: 16
+    query rows, 128 kv rows, the kv rows being the score tile's lanes).
+    Padded kv rows are masked out; padded query rows are sliced off."""
     B, H, T, hd = q.shape
     KVH, S = k.shape[1], k.shape[2]
     g = H // KVH
     scale = hd ** -0.5
-    bq = min(bq, T)
-    bkv = min(bkv, S)
-    while T % bq:
-        bq -= 1
-    while S % bkv:
-        bkv -= 1
-    grid = (B, H, T // bq, S // bkv)
+    bq, Tp = _blocks(T, bq, 1 if interpret else 16)
+    bkv, Sp = _blocks(S, bkv, 1 if interpret else 128)
+    if Tp != T:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, Tp - T), (0, 0)))
+    if Sp != S:
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, Sp - S), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, Sp - S), (0, 0)))
+    grid = (B, H, Tp // bq, Sp // bkv)
 
     kernel = pl.pallas_call(
         functools.partial(_attn_kernel, n_kv=grid[3], bq=bq, bkv=bkv,
                           scale=scale, causal=causal, window=window, cap=cap,
-                          q_offset=q_offset),
+                          q_offset=q_offset, kv_len=S),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),
@@ -101,15 +117,15 @@ def sa_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pl.BlockSpec((1, 1, bkv, hd), lambda b, h, i, j: (b, h // g, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, T, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Tp, hd), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((bq, hd), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
     )
-    return kernel(q, k, v)
+    return kernel(q, k, v)[:, :, :T]

@@ -36,7 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pltpu_compat import CompilerParams as _CompilerParams
 
 EPILOGUES = ("none", "relu", "gelu", "silu")
 MODES = ("exact", "approx")
@@ -182,7 +181,7 @@ def _pallas_fused(a, w, bias, scale, *, act, bm, bn, bk, out_dtype,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )

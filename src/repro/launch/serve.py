@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, reduced_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model as M
 from repro.serve.engine import ServeEngine
 from repro.serve.scheduler import SlotScheduler
@@ -189,7 +190,7 @@ def _print_phases(summary) -> None:
           f"prefill_compiles={summary.get('prefill_compiles')}")
 
 
-def _make_engine(args, cfg, params) -> ServeEngine:
+def make_engine(args, cfg, params) -> ServeEngine:
     return ServeEngine(cfg, params, args.batch, args.cache_len,
                        eos_id=args.eos_id, sync_every=args.sync_every,
                        kv_layout=args.kv, page_size=args.page_size,
@@ -201,7 +202,21 @@ def _make_engine(args, cfg, params) -> ServeEngine:
                        bucket_prompts=args.bucket_prompts or None)
 
 
-def serve_continuous(args, cfg, params, plens) -> dict:
+def stream_digest(finished) -> str:
+    """Digest of the full rid-ordered token streams: the spec-decode CI leg
+    pins REPRO_SPEC_DECODE=1|0 byte-identical through this one field
+    without dumping every token into the summary line."""
+    import hashlib
+    streams = ",".join(
+        f"{r.rid}:{'-'.join(map(str, r.tokens))}"
+        for r in sorted(finished, key=lambda r: r.rid))
+    return hashlib.sha1(streams.encode()).hexdigest()[:16]
+
+
+def serve_stream(args, cfg, params, plens):
+    """One continuous-batching run: engine, seeded request stream, serve.
+    Returns (engine, scheduler, summary); the finished requests and their
+    tokens are on `scheduler.finished`."""
     if args.autotune_decode:
         import os as _os
         paged = (args.kv or _os.environ.get("REPRO_KV", "paged")) == "paged"
@@ -210,23 +225,21 @@ def serve_continuous(args, cfg, params, plens) -> dict:
         preseed_decode_blocks(cfg, args.batch,
                               page_size=args.page_size if paged else None,
                               max_pages=max_pages, spec_k=args.spec_k)
-    engine = _make_engine(args, cfg, params)
+    engine = make_engine(args, cfg, params)
     sched = SlotScheduler(args.batch, eos_id=args.eos_id)
     build_requests(sched, cfg, args.requests, args.rate, plens,
                    args.max_new, args.seed, tier_mix=args.tier_mix,
                    prefix_mix=args.prefix_mix, prefix_len=args.prefix_len)
     summary = engine.serve(sched, greedy=True)
-    # digest of the full rid-ordered token streams: the spec-decode CI leg
-    # pins REPRO_SPEC_DECODE=1|0 byte-identical through this one field
-    # without dumping every token into the summary line
-    import hashlib
-    streams = ",".join(
-        f"{r.rid}:{'-'.join(map(str, r.tokens))}"
-        for r in sorted(sched.finished, key=lambda r: r.rid))
-    summary["stream_digest"] = hashlib.sha1(streams.encode()).hexdigest()[:16]
+    summary["stream_digest"] = stream_digest(sched.finished)
     itl = _itl_p50_ms(sched.finished)
     if itl is not None:
         summary["decode_itl_p50_ms"] = itl
+    return engine, sched, summary
+
+
+def serve_continuous(args, cfg, params, plens) -> dict:
+    engine, sched, summary = serve_stream(args, cfg, params, plens)
     _print_phases(summary)
     if engine.spec_decoding_on() and summary.get("spec_iters"):
         # honest accounting: decode_tok_s above already counts only
@@ -285,8 +298,6 @@ def serve_replicas(args, cfg, params, plens) -> dict:
     paid. Requests keep their global rids across replicas, so the
     aggregate `stream_digest` is comparable with a 1-replica run of the
     same stream."""
-    import hashlib
-
     from repro.serve.scheduler import ReplicaRouter
 
     n = args.decode_replicas
@@ -306,7 +317,7 @@ def serve_replicas(args, cfg, params, plens) -> dict:
     summaries = []
     finished = []
     for i, sched in enumerate(scheds):
-        engine = _make_engine(args, cfg, params)
+        engine = make_engine(args, cfg, params)
         s = engine.serve(sched, greedy=True)
         s["decode_itl_p50_ms"] = _itl_p50_ms(sched.finished)
         summaries.append(s)
@@ -320,9 +331,6 @@ def serve_replicas(args, cfg, params, plens) -> dict:
     def total(key):
         return round(sum(s.get(key) or 0 for s in summaries), 4)
 
-    streams = ",".join(
-        f"{r.rid}:{'-'.join(map(str, r.tokens))}"
-        for r in sorted(finished, key=lambda r: r.rid))
     summary = {
         "replicas": n,
         "requests": sum(s["requests"] for s in summaries),
@@ -341,8 +349,7 @@ def serve_replicas(args, cfg, params, plens) -> dict:
         "wall_s_parallel": round(max(s["wall_s"] for s in summaries), 4),
         "disagg": summaries[0].get("disagg"),
         "ready_depth_p50": summaries[0].get("ready_depth_p50"),
-        "stream_digest":
-            hashlib.sha1(streams.encode()).hexdigest()[:16],
+        "stream_digest": stream_digest(finished),
     }
     itl = _itl_p50_ms(finished)
     if itl is not None:
@@ -390,7 +397,7 @@ def serve_static(args, cfg, params, plens) -> dict:
             if decode_s > 0 else 0.0}
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -482,15 +489,35 @@ def main(argv=None):
                     help="pre-seed decode-shape GEMV blocks (autotune."
                          "tune_decode) before the first chunk compiles")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
+
+
+def load_model(args):
+    """(cfg, params) for `args`: the registry config (or its reduced
+    variant) with seeded random weights. Published widths store bf16
+    weights: every GEMM quantizes its inputs to bf16 on the TPU anyway
+    (PrecisionPolicy.cast_in), and fp32 would double the weight bytes
+    (12.6 GiB for granite-moe-3b on a 16 GB chip). The init is jitted so
+    the fp32 draws never coexist with the bf16 tree on the device."""
+    import functools
 
     cfg = (reduced_config(args.arch,
                           layers_per_period=args.layers_per_period,
                           width=args.width)
            if args.reduced else get_config(args.arch))
-    params = M.init_params(jax.random.key(args.seed), cfg)
+    dtype = jnp.float32 if args.reduced else jnp.bfloat16
+    params = jax.jit(functools.partial(M.init_params, cfg=cfg,
+                                       dtype=dtype))(
+        jax.random.key(args.seed))
     if args.spec_warmup > 0:
         params = spec_warmup_train(cfg, params, args.spec_warmup, args.seed)
+    return cfg, params
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    use_compile_cache()
+    cfg, params = load_model(args)
     plens = [int(x) for x in args.prompt_lens.split(",")]
     # prefix-mix prompts grow by the shared system prompt; size the default
     # per-request capacity to still fit them

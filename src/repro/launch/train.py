@@ -18,6 +18,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config, reduced_config
 from repro.data.pipeline import SyntheticLM, Prefetcher
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.parallel import sharding as S
 from repro.train import checkpoint as CKPT
@@ -25,6 +26,17 @@ from repro.train.fault import PreemptionGuard, StragglerWatchdog
 from repro.train.optimizer import AdamW, warmup_cosine
 from repro.train.step import make_train_step
 from repro.train.train_state import TrainState, init_state
+
+
+def place_state(cfg, state: TrainState, mesh):
+    """Shard a train state onto `mesh` by the parameter rules
+    (parallel/sharding.py); returns (placed state, its shardings)."""
+    pshard = S.param_shardings(cfg, state.params, mesh)
+    state_shard = TrainState(step=NamedSharding(mesh, P()), params=pshard,
+                             opt_state=type(state.opt_state)(
+                                 count=NamedSharding(mesh, P()),
+                                 mu=pshard, nu=pshard))
+    return jax.device_put(state, state_shard), state_shard
 
 
 def main(argv=None):
@@ -44,6 +56,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log", default=None, help="metrics JSONL path")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     mesh = make_host_mesh(args.model_parallel)
@@ -52,12 +65,7 @@ def main(argv=None):
     step_fn = make_train_step(cfg, opt, accum_steps=args.accum)
 
     state = init_state(jax.random.key(args.seed), cfg, opt)
-    pshard = S.param_shardings(cfg, state.params, mesh)
-    state_shard = TrainState(step=NamedSharding(mesh, P()), params=pshard,
-                             opt_state=type(state.opt_state)(
-                                 count=NamedSharding(mesh, P()),
-                                 mu=pshard, nu=pshard))
-    state = jax.device_put(state, state_shard)
+    state, state_shard = place_state(cfg, state, mesh)
 
     start = 0
     if args.resume and args.ckpt_dir and CKPT.latest_step(args.ckpt_dir) is not None:

@@ -580,6 +580,7 @@ def attention_block(x, p, cfg, meta, positions, cache: KVCache | None = None,
                              cap=cfg.attn_softcap)
     else:
         from repro.core import optflags
+        k_att, v_att = k, v
         if cache is not None and prefix_len:
             # continued prefill (serve prefix-cache hit): suffix queries
             # attend over [cached prefix ‖ fragment] at their absolute
@@ -589,24 +590,26 @@ def attention_block(x, p, cfg, meta, positions, cache: KVCache | None = None,
             # cache round-trip is value-preserving (fp32 cache, or a bf16
             # cache of values the SA contract re-quantizes to bf16 anyway),
             # so the suffix rows come out bit-identical to full prefill.
-            kp = cache.k[:, :prefix_len].astype(k.dtype)
-            vp = cache.v[:, :prefix_len].astype(v.dtype)
-            o = blockwise_attention(
-                q, jnp.concatenate([kp, k], axis=1),
-                jnp.concatenate([vp, v], axis=1), causal=causal,
-                window=window, cap=cfg.attn_softcap, q_offset=prefix_len)
-        elif cache is not None and optflags.enabled("pallas_attention"):
-            # serving prefill is forward-only: use the Pallas flash kernel
-            # (VMEM-resident softmax state; kernels/sa_attention.py)
+            k_att = jnp.concatenate(
+                [cache.k[:, :prefix_len].astype(k.dtype), k], axis=1)
+            v_att = jnp.concatenate(
+                [cache.v[:, :prefix_len].astype(v.dtype), v], axis=1)
+        if cache is not None and optflags.enabled("pallas_attention"):
+            # serving prefill (full or continued) is forward-only: use the
+            # Pallas flash kernel (VMEM-resident softmax state;
+            # kernels/sa_attention.py) on SA-contract inputs
+            from repro.core.precision import current_policy
             from repro.kernels.ops import sa_attention
-            o = sa_attention(q.transpose(0, 2, 1, 3),
-                             k.transpose(0, 2, 1, 3),
-                             v.transpose(0, 2, 1, 3),
+            pol = current_policy()
+            o = sa_attention(*(pol.cast_in(x).transpose(0, 2, 1, 3)
+                               for x in (q, k_att, v_att)),
                              causal=causal, window=window,
-                             cap=cfg.attn_softcap).transpose(0, 2, 1, 3)
+                             cap=cfg.attn_softcap,
+                             q_offset=prefix_len).transpose(0, 2, 1, 3)
         else:
-            o = blockwise_attention(q, k, v, causal=causal, window=window,
-                                    cap=cfg.attn_softcap)
+            o = blockwise_attention(q, k_att, v_att, causal=causal,
+                                    window=window, cap=cfg.attn_softcap,
+                                    q_offset=prefix_len)
         if cache is not None:            # prefill: fill the cache
             if isinstance(cache, PagedKVCache):
                 # the serve engine prefills a dense batch-1 fragment and

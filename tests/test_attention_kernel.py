@@ -105,3 +105,17 @@ def test_prefill_via_kernel_matches_jnp_path():
         for a, b in zip(jax.tree.leaves(cache_a), jax.tree.leaves(cache_b)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-4, atol=2e-4)
+
+
+def test_tpu_blocks_tile_aligned_for_every_length():
+    """On TPU every prompt length gets Mosaic-legal blocks: query blocks of
+    whole 16-row tiles, kv blocks of whole 128-lane score tiles, at most
+    one block's worth of padding, and no block above the 512-row cap by
+    more than one tile."""
+    from repro.kernels.sa_attention import _blocks
+    for n in range(1, 4097):
+        for cap, unit in ((512, 16), (512, 128)):
+            b, padded = _blocks(n, cap, unit)
+            assert b % unit == 0 and padded % b == 0
+            assert n <= padded < n + padded // b * unit
+            assert b <= cap + unit - 1 or b <= unit

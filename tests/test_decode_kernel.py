@@ -265,3 +265,56 @@ def test_serve_fused_equals_gather_tokens(monkeypatch):
     fused, gather = run("fused"), run("gather")
     assert fused == gather
     assert all(len(v) for v in fused.values())
+
+
+# ---------------------------------------------------------------------------
+# TPU grid legality (Mosaic block rules) — checked without a chip
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("kvh,P,psz,want,got", [
+    # granite decode shape: head tiles must be KVH or 8ℤ, page blocks fill
+    # 128 lanes
+    (8, 128, 16, (8, 1), (8, 8)),
+    (8, 128, 16, (128, 8), (128, 8)),
+    (16, 128, 16, (4, 2), (8, 8)),
+    # a block table narrower than 128 lanes walks in one step
+    (8, 4, 16, (1, 1), (4, 8)),
+    (8, 2048, 32, (2, 8), (4, 8)),
+])
+def test_tpu_blocks_snap_to_legal_grid(kvh, P, psz, want, got):
+    from repro.kernels.sa_decode_attention import tpu_blocks
+    ppb, hb = tpu_blocks(P, kvh, psz, *want)
+    assert (ppb, hb) == got
+    assert P % ppb == 0 and kvh % hb == 0
+    assert hb == kvh or hb % 8 == 0
+    assert ppb == P or ppb * psz % 128 == 0
+
+
+def test_tpu_autotune_candidates_are_legal(monkeypatch):
+    """Every (ppb, hb) the decode-attention autotuner can sweep or fall
+    back to on TPU is a grid Mosaic accepts."""
+    from repro.kernels import autotune
+    from repro.kernels.sa_decode_attention import tpu_blocks
+    monkeypatch.setattr(autotune, "backend_key", lambda: "tpu")
+    for kvh, P, psz in ((8, 128, 16), (8, 2048, 16), (2, 4, 16), (32, 64, 32)):
+        cands = autotune.decode_attn_candidates(kvh, P, psz)
+        cands.append(autotune.default_decode_attn_blocks(kvh, P, psz))
+        for c in cands:
+            assert tpu_blocks(P, kvh, psz, *c) == c, (kvh, P, psz, c)
+
+
+def test_tpu_unservable_shapes_raise():
+    """No legal grid (page size off the bf16 sublane tile) or a VMEM need
+    past the cap is a clear error on the chip path, never a silent fallback
+    to interpret mode or the gather path."""
+    q, k, v, pp, bt, pos = _workload(0, 1, 2, 2, 16, 8, 4, mapped=2)
+    with pytest.raises(ValueError, match="page_size % 16"):
+        ops.paged_decode_attention(q, k, v, pp, bt, pos, interpret=False)
+    B, kvh, g, hd, psz, P = 1, 8, 3, 64, 16, 4096
+    q = jnp.zeros((B, 1, kvh * g, hd), jnp.float32)
+    pool = jnp.zeros((2, psz, kvh, hd), jnp.float32)
+    with pytest.raises(ValueError, match="VMEM"):
+        ops.paged_decode_attention(
+            q, pool, pool, jnp.zeros((2, psz), jnp.int32),
+            jnp.zeros((B, P), jnp.int32), jnp.zeros((B,), jnp.int32),
+            interpret=False)

@@ -15,7 +15,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 from repro.configs import reduced_config
 from repro.parallel import sharding as S
 from repro.parallel.compression import compressed_psum
@@ -35,7 +35,8 @@ state0 = init_state(jax.random.key(0), cfg, opt)
 _, m_ref = jax.jit(step)(state0, batch)
 
 # 4x2 (data, model) mesh
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto, AxisType.Auto))
 S.set_active_mesh(mesh)
 state = init_state(jax.random.key(0), cfg, opt)
 pshard = S.param_shardings(cfg, state.params, mesh)
@@ -53,7 +54,7 @@ with mesh:
 ok_loss = abs(float(m["loss"]) - float(m_ref["loss"])) < 5e-3
 
 # shard_map compressed gradient psum across the data axis
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 g_local = jnp.arange(32, dtype=jnp.float32).reshape(4, 8) * 0.01
 def sync(g):
     summed, resid = compressed_psum({"g": g}, "data", codec="int8")
@@ -91,14 +92,15 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 os.environ["REPRO_DECODE_ATTN"] = "fused"
 import json
 import jax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.configs import reduced_config
 from repro.models.config import ShapeCfg
 from repro.models.layers import PagedKVCache
 from repro.launch import specs as SP
 from repro.parallel.sharding import set_active_mesh
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto, AxisType.Auto))
 set_active_mesh(mesh)
 cfg = reduced_config("qwen2.5-14b")
 shape = ShapeCfg("decode_paged_smoke", 256, 8, "decode")
@@ -112,7 +114,8 @@ pools = [s for s in jax.tree.leaves(
 assert pools, "decode cell lowered without a paged leaf"
 k_spec = pools[0].k.spec
 bt_spec = pools[0].block_table.spec
-ok_pages = k_spec[1] == ("data",) and bt_spec == P(None, None, None)
+# JAX >= 0.8 normalizes a 1-tuple axis entry to the bare name
+ok_pages = k_spec[1] in ("data", ("data",)) and bt_spec == P(None, None, None)
 with mesh:
     jitted = jax.jit(step_fn, in_shardings=in_sh, out_shardings=out_sh,
                      donate_argnums=(2,))
@@ -146,7 +149,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.configs import reduced_config
 from repro.models import model as M
 from repro.models.layers import KVCache, PagedKVCache
@@ -154,7 +157,8 @@ from repro.parallel import sharding as S
 from repro.serve.engine import ServeEngine
 from repro.train.step import make_prefill_step
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto, AxisType.Auto))
 cfg = reduced_config("qwen2.5-14b")
 psz = 8
 pool_pages = 12            # % data size (4) == 0: the page dim must shard
@@ -184,7 +188,8 @@ cspecs = S.cache_specs(cfg, cache, mesh, B)
 pool = [s for s in jax.tree.leaves(
             cspecs, is_leaf=lambda x: isinstance(x, PagedKVCache))
         if isinstance(s, PagedKVCache)][0]
-ok_pool = pool.k[1] == ("data",) and pool.block_table == P(None, None, None)
+ok_pool = (pool.k[1] in ("data", ("data",))
+           and pool.block_table == P(None, None, None))
 
 # fragment sharding: token dim REPLICATED over data (whole-page handoff —
 # each data shard keeps its local pages at the scatter), heads over model

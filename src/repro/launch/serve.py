@@ -177,17 +177,21 @@ def _itl_p50_ms(finished) -> float | None:
 
 def _print_phases(summary) -> None:
     """Honest per-phase wall split (engine.serve accounting comment):
-    prefill/decode busy walls are real measurements in both modes;
+    prefill/decode walls are real measurements in both modes;
     decode_stall is the decode-blocking component — the whole admission
-    prefill in unified mode, only the synced handoff in two-pool mode."""
+    prefill in unified mode, only the synced handoff in two-pool mode.
+    The line ends with every span's total seconds and count."""
+    spans = " ".join(f"{k}={v['s']:.4f}s/{v['n']}"
+                     for k, v in sorted(summary.get("spans", {}).items()))
     print(f"[phases] disagg={summary.get('disagg')} "
-          f"prefill_busy={summary.get('prefill_busy_s')}s "
-          f"decode_busy={summary.get('decode_busy_s')}s "
+          f"prefill={summary.get('prefill_s')}s "
+          f"decode={summary.get('decode_s')}s "
           f"handoff={summary.get('handoff_s')}s "
           f"decode_stall={summary.get('decode_stall_s')}s "
           f"itl_p50={summary.get('decode_itl_p50_ms')}ms "
           f"ready_p50={summary.get('ready_depth_p50')} "
-          f"prefill_compiles={summary.get('prefill_compiles')}")
+          f"prefill_compiles={summary.get('prefill_compiles')} "
+          f"spans: {spans}")
 
 
 def make_engine(args, cfg, params) -> ServeEngine:
@@ -331,16 +335,24 @@ def serve_replicas(args, cfg, params, plens) -> dict:
     def total(key):
         return round(sum(s.get(key) or 0 for s in summaries), 4)
 
+    spans: dict[str, dict] = {}
+    for s in summaries:
+        for k, v in s["spans"].items():
+            t = spans.setdefault(k, {"n": 0, "s": 0.0})
+            t["n"] += v["n"]
+            t["s"] += v["s"]
+
     summary = {
         "replicas": n,
         "requests": sum(s["requests"] for s in summaries),
         "generated_tokens": sum(s["generated_tokens"] for s in summaries),
         "rejected": sum(s.get("rejected", 0) for s in summaries),
         "pages_leaked": total("pages_leaked"),
-        "prefill_busy_s": total("prefill_busy_s"),
-        "decode_busy_s": total("decode_busy_s"),
+        "prefill_s": total("prefill_s"),
+        "decode_s": total("decode_s"),
         "handoff_s": total("handoff_s"),
         "decode_stall_s": total("decode_stall_s"),
+        "spans": spans,
         "prefill_compiles": sum(s.get("prefill_compiles", 0)
                                 for s in summaries),
         # serial = what this single-host emulation paid; parallel = the

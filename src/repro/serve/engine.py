@@ -72,10 +72,17 @@ from repro.train.step import (make_bucketed_prefill_step, make_draft_step,
                               make_prefill_step, make_serve_step,
                               make_verify_step)
 from .scheduler import PageAllocator, SlotScheduler
+from .trace import Recorder
 
 
 def _round_up(x: int, m: int) -> int:
     return -(-int(x) // m) * m
+
+
+def _jit(phase: str, fn, **kw):
+    """`jax.jit` of `fn` traced under `jax.named_scope(phase)`: the
+    program's device ops carry the engine phase in their sources."""
+    return jax.jit(jax.named_scope(phase)(fn), **kw)
 
 
 def _bucket_len(n: int) -> int:
@@ -147,7 +154,7 @@ class ServeEngine:
             for j in range(cfg.stack_period))
         self._frag_floor = (cfg.window if has_local and cfg.window
                             and cfg.window < self.max_seq_len else 1)
-        self._prefill = jax.jit(make_prefill_step(cfg))
+        self._prefill = _jit("prefill", make_prefill_step(cfg))
         # continued-prefill variants, one jitted closure per shared-prefix
         # length (prefix_len is trace-time state like the arithmetic mode);
         # drivers already quantize prompt lengths, and shared spans are
@@ -174,13 +181,17 @@ class ServeEngine:
         # entry would silently serve whichever variant traced first — the
         # same aliasing the divergence probe hit with shared mode traces.
         self._chunks: dict[tuple[int, bool, str, int], Any] = {}
-        self._insert = jax.jit(self._insert_impl, donate_argnums=(0,))
-        self._scatter = jax.jit(self._scatter_impl, donate_argnums=(0,))
-        self._bind = jax.jit(self._bind_impl, donate_argnums=(0,))
-        self._clear_slot = jax.jit(self._clear_slot_impl, donate_argnums=(0,))
-        self._load_prefix = jax.jit(self._load_prefix_impl,
-                                    static_argnums=(3,), donate_argnums=(0,))
+        self._insert = _jit("insert", self._insert_impl, donate_argnums=(0,))
+        self._scatter = _jit("scatter", self._scatter_impl,
+                             donate_argnums=(0,))
+        self._bind = _jit("bind", self._bind_impl, donate_argnums=(0,))
+        self._clear_slot = _jit("clear_slot", self._clear_slot_impl,
+                                donate_argnums=(0,))
+        self._load_prefix = _jit("load_prefix", self._load_prefix_impl,
+                                 static_argnums=(3,), donate_argnums=(0,))
         self.last_stats: dict[str, float] = {}
+        # the spans and counters of the last serve() (serve/trace.py)
+        self.recorder = Recorder()
 
     def new_cache(self, batch: int | None = None):
         return M.init_cache(self.cfg, batch or self.batch, self.cache_len,
@@ -426,7 +437,7 @@ class ServeEngine:
         """Jitted prefill closure for one static shared-prefix length."""
         fn = self._prefills.get(prefix_len)
         if fn is None:
-            fn = jax.jit(make_prefill_step(self.cfg, prefix_len))
+            fn = _jit("prefill", make_prefill_step(self.cfg, prefix_len))
             self._prefills[prefix_len] = fn
         return fn
 
@@ -436,7 +447,8 @@ class ServeEngine:
         jit's shape key, so one closure serves every bucket."""
         fn = self._bucketed_prefills.get(prefix_len)
         if fn is None:
-            fn = jax.jit(make_bucketed_prefill_step(self.cfg, prefix_len))
+            fn = _jit("bucketed_prefill",
+                      make_bucketed_prefill_step(self.cfg, prefix_len))
             self._bucketed_prefills[prefix_len] = fn
         return fn
 
@@ -446,7 +458,12 @@ class ServeEngine:
         the suffix prefill — bucketed when `bucketing_on()` — and pick the
         first token. Returns (frag, first, row, keep, rng) where `row` is
         the -1-padded (max_pages,) page run and `keep` the shared leading
-        page count (both None for ring engines)."""
+        page count (both None for ring engines).
+
+        Everything after the bucket choice is the `prefill` span, which
+        ends with the first token's fetch; the counters take the real
+        prompt tokens prefilled (`prefill_tokens`) and the positions
+        computed for them after bucketing (`prefill_positions`)."""
         paged = self.kv_layout == "paged"
         shared = req.shared_tokens if paged else 0
         suffix = req.prompt_len - shared
@@ -456,47 +473,52 @@ class ServeEngine:
             b = _bucket_len(suffix)
             if shared + b <= cap:
                 Tb = b
-        frag = (self.new_frag(shared + Tb) if paged
-                else self.new_cache(batch=1))
-        if shared:
-            # prefix-cache hit: pre-load the shared span's KV from the
-            # hit pages (plus the COW donor's partial tail) and prefill
-            # only the uncached suffix — TTFT stays honest, it times the
-            # load + suffix prefill actually paid
-            src = list(req.pages[:shared // self.page_size])
-            if req.cow_src is not None:
-                src.append(req.cow_src)
-            frag = self._load_prefix(
-                frag, cache, jnp.asarray(src, jnp.int32), shared)
-            if req.cow_src is not None:
-                # the donor's rows are in the fragment now; the scatter
-                # writes them into the request's own tail page (the
-                # copy), so the donor's copy-window lease can drop
-                scheduler.cow_done(req)
-        tokens = np.asarray(req.prompt[shared:], np.int32)
-        if Tb != suffix or self.bucketing_on():
-            tokens = np.pad(tokens, (0, Tb - suffix))
-            self._prefill_shapes.add((shared, Tb, True))
-            logits, frag = self._bucketed_prefill_for(shared)(
-                self.params, jnp.asarray(tokens)[None], frag,
-                jnp.asarray(suffix - 1, jnp.int32),
-                jnp.asarray(req.prompt_len, jnp.int32))
-        else:
-            self._prefill_shapes.add((shared, suffix, False))
-            logits, frag = self._prefill_for(shared)(
-                self.params, jnp.asarray(tokens)[None], frag, None)
-        if greedy:
-            first = int(np.asarray(jnp.argmax(logits[0, -1])))
-        else:
-            rng, k = jax.random.split(rng)
-            first = int(np.asarray(
-                jax.random.categorical(k, logits[0, -1])))
-        row = keep = None
-        if paged:
-            r = np.full((self.max_pages,), -1, np.int32)
-            r[:len(req.pages)] = req.pages
-            row = jnp.asarray(r)
-            keep = jnp.asarray(shared // self.page_size, jnp.int32)
+        rec = self.recorder
+        rec.count("prefill_tokens", suffix)
+        rec.count("prefill_positions", Tb)
+        with rec.span("prefill", req.rid, tokens=suffix, positions=Tb,
+                      shared=shared):
+            frag = (self.new_frag(shared + Tb) if paged
+                    else self.new_cache(batch=1))
+            if shared:
+                # prefix-cache hit: pre-load the shared span's KV from the
+                # hit pages (plus the COW donor's partial tail) and prefill
+                # only the uncached suffix — TTFT stays honest, it times
+                # the load + suffix prefill actually paid
+                src = list(req.pages[:shared // self.page_size])
+                if req.cow_src is not None:
+                    src.append(req.cow_src)
+                frag = self._load_prefix(
+                    frag, cache, jnp.asarray(src, jnp.int32), shared)
+                if req.cow_src is not None:
+                    # the donor's rows are in the fragment now; the
+                    # scatter writes them into the request's own tail page
+                    # (the copy), so the donor's copy-window lease can drop
+                    scheduler.cow_done(req)
+            tokens = np.asarray(req.prompt[shared:], np.int32)
+            if Tb != suffix or self.bucketing_on():
+                tokens = np.pad(tokens, (0, Tb - suffix))
+                self._prefill_shapes.add((shared, Tb, True))
+                logits, frag = self._bucketed_prefill_for(shared)(
+                    self.params, jnp.asarray(tokens)[None], frag,
+                    jnp.asarray(suffix - 1, jnp.int32),
+                    jnp.asarray(req.prompt_len, jnp.int32))
+            else:
+                self._prefill_shapes.add((shared, suffix, False))
+                logits, frag = self._prefill_for(shared)(
+                    self.params, jnp.asarray(tokens)[None], frag, None)
+            if greedy:
+                first = int(np.asarray(jnp.argmax(logits[0, -1])))
+            else:
+                rng, k = jax.random.split(rng)
+                first = int(np.asarray(
+                    jax.random.categorical(k, logits[0, -1])))
+            row = keep = None
+            if paged:
+                r = np.full((self.max_pages,), -1, np.int32)
+                r[:len(req.pages)] = req.pages
+                row = jnp.asarray(r)
+                keep = jnp.asarray(shared // self.page_size, jnp.int32)
         return frag, first, row, keep, rng
 
     @staticmethod
@@ -558,7 +580,7 @@ class ServeEngine:
                     body, (tok, cache, pos, rng), length=steps)
                 return tok, cache, pos, rng, toks
 
-            jitted = jax.jit(chunk, donate_argnums=(2,))
+            jitted = _jit("decode_chunk", chunk, donate_argnums=(2,))
 
             def run(*args, _jitted=jitted, _mode=mode):
                 pol = dataclasses.replace(current_policy(), mode=_mode)
@@ -634,7 +656,7 @@ class ServeEngine:
                     body, (tok, cache, pos, rng), length=iters)
                 return tok, cache, pos, rng, toks, accs
 
-            jitted = jax.jit(chunk, donate_argnums=(2,))
+            jitted = _jit("spec_chunk", chunk, donate_argnums=(2,))
 
             def run(*args, _jitted=jitted, _mode=mode):
                 pol = dataclasses.replace(current_policy(), mode=_mode)
@@ -826,7 +848,9 @@ class ServeEngine:
 
         Per-request results/metrics live on the `Request` objects
         (`scheduler.finished`); returns `scheduler.summary()` merged with
-        the engine's prefill/decode wall-time split. Text-only for now:
+        the engine's prefill/decode wall-time split, which sums the spans
+        this call recorded (`self.recorder`, reset here; per-name totals
+        under `spans`, the counters under `counters`). Text-only for now:
         per-slot frontends would need fragment caches of their own.
         """
         assert scheduler.n_slots == self.batch, (
@@ -847,21 +871,12 @@ class ServeEngine:
 
         def now():
             return clock() - t0 + skew
+        rec = self.recorder
+        rec.reset(now)
+        span = rec.span
         cache = self.new_pool() if paged else self.new_cache()
         tok = jnp.zeros((B,), jnp.int32)
         pos = jnp.zeros((B,), jnp.int32)
-        prefill_s = decode_s = 0.0
-        # per-phase wall split (honest accounting, DESIGN.md §10): handoff
-        # = the page scatter / block-table splice walls; decode_stall = the
-        # admission wall spent while ≥1 OTHER slot sat idle waiting — the
-        # decode-blocking component. Unified mode charges the whole
-        # prefill+insert to the stall (the slots genuinely wait on it);
-        # two-pool mode charges only the handoff sync, the part a real
-        # two-pool deployment (prefill on its own devices) would retain.
-        # Single-host caveat: both pools share this process's device, so
-        # the stall split is the modeled decode-blocking time, while
-        # wall_s/ITL remain real measurements.
-        handoff_s = decode_stall_s = 0.0
         disagg = self.disagg_on()
         mesh = shardlib.active_mesh()
         chunk_modes = {"exact": 0, "approx": 0}
@@ -872,6 +887,9 @@ class ServeEngine:
         # boundaries) comparable between the two paths
         spec_iters = (max(1, -(-self.sync_every // (self.spec_k + 1)))
                       if spec else 0)
+        # token positions one chunk can emit per slot (decode_steps)
+        chunk_steps = (spec_iters * (self.spec_k + 1) if spec
+                       else self.sync_every)
         spec_chunks = 0
 
         # pre-compile the decode chunk before the timed loop: the first
@@ -901,7 +919,8 @@ class ServeEngine:
             # here never wipes a live row).
             nonlocal cache
             for freed in scheduler.drain_freed():
-                cache = self._clear_slot(cache, freed)
+                with span("clear_slot", slot=freed):
+                    cache = self._clear_slot(cache, freed)
 
         while not scheduler.drained():
             if paged:
@@ -915,14 +934,14 @@ class ServeEngine:
                     req = scheduler.admit_ready(slot, now())
                     if req is None:
                         break
-                    t_h = now()
-                    r = np.full((self.max_pages,), -1, np.int32)
-                    r[:len(req.pages)] = req.pages
-                    cache = self._bind(cache, jnp.asarray(r), slot)
-                    # first token came from finish_prefill; resume after it
-                    tok = tok.at[slot].set(req.tokens[0])
-                    pos = pos.at[slot].set(req.prompt_len)
-                    handoff_s += now() - t_h
+                    with span("bind", req.rid):
+                        r = np.full((self.max_pages,), -1, np.int32)
+                        r[:len(req.pages)] = req.pages
+                        cache = self._bind(cache, jnp.asarray(r), slot)
+                        # first token came from finish_prefill; resume
+                        # after it
+                        tok = tok.at[slot].set(req.tokens[0])
+                        pos = pos.at[slot].set(req.prompt_len)
                 # prefill pool: up to `prefill_workers` prefills per chunk
                 # interval, stopping once the ready queue could refill
                 # every slot (prefilling further ahead only pins pages
@@ -938,33 +957,30 @@ class ServeEngine:
                         # the pool / block table — retire it as rejected
                         scheduler.reject_prefill(req, now())
                         continue
-                    t_p = now()
-                    frag, first, row, keep, rng = self._prefill_request(
-                        scheduler, req, cache, greedy, rng)
-                    dt = now() - t_p
-                    prefill_s += dt
-                    # the handoff: reshard the staged fragment onto the
-                    # pool's layout (page dim sharded over data axes —
-                    # whole pages move, no per-token traffic), scatter it
-                    # in, and sync — the one wall decode can block on
-                    t_h = now()
-                    if mesh is not None:
-                        frag = shardlib.reshard_handoff(frag, mesh,
-                                                        self.cfg)
-                    cache = self._scatter(cache, frag, row, keep)
-                    jax.block_until_ready(cache)
-                    dt_h = now() - t_h
-                    handoff_s += dt_h
-                    if scheduler.num_active() > 0:
-                        decode_stall_s += dt_h
-                    # register BEFORE the scheduler sees the first token:
-                    # a first-token EOS retires the request immediately,
-                    # and the registered pages must park as cached, not
-                    # return to the free list
-                    scheduler.pages.prefix_register(req.prompt, req.pages,
-                                                    req.tier)
-                    scheduler.finish_prefill(req, first, now(),
-                                             prefill_s=dt)
+                    with span("admission", req.rid):
+                        frag, first, row, keep, rng = self._prefill_request(
+                            scheduler, req, cache, greedy, rng)
+                        # the handoff: reshard the staged fragment onto the
+                        # pool's layout (page dim sharded over data axes —
+                        # whole pages move, no per-token traffic), scatter
+                        # it in, and sync — the one wall decode can block
+                        # on, a stall while any decode slot is live
+                        with span("scatter", req.rid,
+                                  stall=scheduler.num_active() > 0):
+                            if mesh is not None:
+                                frag = shardlib.reshard_handoff(frag, mesh,
+                                                                self.cfg)
+                            cache = self._scatter(cache, frag, row, keep)
+                            jax.block_until_ready(cache)
+                        # register BEFORE the scheduler sees the first
+                        # token: a first-token EOS retires the request
+                        # immediately, and the registered pages must park
+                        # as cached, not return to the free list
+                        scheduler.pages.prefix_register(req.prompt,
+                                                        req.pages, req.tier)
+                        scheduler.finish_prefill(
+                            req, first, now(),
+                            prefill_s=rec.last("prefill").dur)
                     n_pf += 1
             else:
                 for slot in scheduler.free_slots():
@@ -980,34 +996,30 @@ class ServeEngine:
                         # in-flight slots keep decoding.
                         scheduler.reject(slot, now())
                         continue
-                    t_p = now()
-                    frag, first, row, keep, rng = self._prefill_request(
-                        scheduler, req, cache, greedy, rng)
-                    t_h = now()
-                    if paged:
-                        cache = self._insert(cache, frag, slot, row, keep)
-                        # register this prompt's pages for reuse BEFORE
-                        # the scheduler sees the first token: a first-
-                        # token EOS retires the request immediately, and
-                        # the registered pages must park as cached, not
-                        # return to the free list
-                        scheduler.pages.prefix_register(req.prompt,
-                                                        req.pages, req.tier)
-                    else:
-                        cache = self._insert(cache, frag, slot)
-                    # dispatch-only wall: the unified splice overlaps the
-                    # next admission, unlike the two-pool synced handoff
-                    handoff_s += now() - t_h
-                    tok = tok.at[slot].set(first)
-                    pos = pos.at[slot].set(req.prompt_len)
-                    dt = now() - t_p
-                    prefill_s += dt
-                    if scheduler.num_active() > 1:
-                        # every other live slot sat idle through this
-                        # admission's prefill — the stall disaggregation
-                        # exists to remove
-                        decode_stall_s += dt
-                    scheduler.start(slot, first, now(), prefill_s=dt)
+                    # every other live slot sits idle through this
+                    # admission — the stall disaggregation exists to remove
+                    with span("admission", req.rid,
+                              stall=scheduler.num_active() > 1) as adm:
+                        frag, first, row, keep, rng = self._prefill_request(
+                            scheduler, req, cache, greedy, rng)
+                        # dispatch-only: the unified splice overlaps the
+                        # next admission, unlike the two-pool synced handoff
+                        with span("insert", req.rid):
+                            if paged:
+                                cache = self._insert(cache, frag, slot, row,
+                                                     keep)
+                                # register this prompt's pages for reuse
+                                # BEFORE the scheduler sees the first token:
+                                # a first-token EOS retires the request
+                                # immediately, and the registered pages must
+                                # park as cached, not return to the free list
+                                scheduler.pages.prefix_register(
+                                    req.prompt, req.pages, req.tier)
+                            else:
+                                cache = self._insert(cache, frag, slot)
+                        tok = tok.at[slot].set(first)
+                        pos = pos.at[slot].set(req.prompt_len)
+                    scheduler.start(slot, first, now(), prefill_s=adm.dur)
             if paged:
                 clear_freed()
 
@@ -1017,17 +1029,19 @@ class ServeEngine:
                     continue
                 # queue non-empty but nothing has arrived yet: wait for the
                 # next arrival instead of spinning
-                nxt = scheduler.next_arrival()
-                if nxt is None:
-                    break
-                wait = nxt - now()
-                if wait > 0:
-                    before = clock()
-                    time.sleep(min(wait, 0.05))
-                    if clock() == before:
-                        # injected/frozen clock: real sleeps cannot advance
-                        # it — fast-forward engine time to the arrival
-                        skew += wait
+                with span("wait_arrival"):
+                    nxt = scheduler.next_arrival()
+                    if nxt is None:
+                        break
+                    wait = nxt - now()
+                    if wait > 0:
+                        before = clock()
+                        time.sleep(min(wait, 0.05))
+                        if clock() == before:
+                            # injected/frozen clock: real sleeps cannot
+                            # advance it — fast-forward engine time to the
+                            # arrival
+                            skew += wait
                 continue
 
             # chunk datapath: approximate only when EVERY active slot is a
@@ -1035,28 +1049,56 @@ class ServeEngine:
             # slots sharing a chunk with premium get exact arithmetic (the
             # tier is a quality floor). Tier-affine admission (scheduler)
             # phase-separates mixed streams so all-bulk chunks do occur.
-            active_tiers = {s.req.tier for s in scheduler.slots
-                            if s.req is not None}
-            mode = "approx" if active_tiers == {"bulk"} else "exact"
+            live = [s.req for s in scheduler.slots if s.req is not None]
+            mode = ("approx" if {r.tier for r in live} == {"bulk"}
+                    else "exact")
             chunk_modes[mode] += 1
-            t_d = now()
-            if spec:
-                tok, cache, pos, rng, toks, accs = self._spec_chunk_fn(
-                    spec_iters, greedy, mode, self.spec_k)(
-                    self.params, tok, cache, pos, None, rng)
-                toks_np = np.asarray(toks)   # the chunk's single host sync
-                accs_np = np.asarray(accs)
-                decode_s += now() - t_d
-                spec_chunks += 1
-                scheduler.observe_spec(toks_np, accs_np, now(), mode=mode)
-            else:
-                tok, cache, pos, rng, toks = self._chunk_fn(
-                    self.sync_every, greedy, mode)(self.params, tok, cache,
-                                                   pos, None, rng)
-                toks_np = np.asarray(toks)   # the chunk's single host sync
-                decode_s += now() - t_d
-                scheduler.observe(toks_np, now(), mode=mode)
+            with span("decode_chunk", steps=chunk_steps, live=len(live),
+                      mode=mode):
+                if spec:
+                    tok, cache, pos, rng, toks, accs = self._spec_chunk_fn(
+                        spec_iters, greedy, mode, self.spec_k)(
+                        self.params, tok, cache, pos, None, rng)
+                else:
+                    tok, cache, pos, rng, toks = self._chunk_fn(
+                        self.sync_every, greedy, mode)(
+                        self.params, tok, cache, pos, None, rng)
+                with span("chunk_wait"):
+                    toks_np = np.asarray(toks)   # the chunk's single sync
+                    if spec:
+                        accs_np = np.asarray(accs)
+            rec.count("decode_steps", chunk_steps)
+            rec.count("decode_slot_steps", chunk_steps * B)
+            before = sum(r.n_generated for r in live)
+            with span("observe"):
+                if spec:
+                    spec_chunks += 1
+                    scheduler.observe_spec(toks_np, accs_np, now(),
+                                           mode=mode)
+                else:
+                    scheduler.observe(toks_np, now(), mode=mode)
+            rec.count("decode_tokens_kept",
+                      sum(r.n_generated for r in live) - before)
 
+        # the wall split, summed over spans (honest accounting, DESIGN.md
+        # §10): handoff = the page scatter / block-table splice walls;
+        # decode_stall = the admission wall spent while ≥1 OTHER slot sat
+        # idle waiting — the decode-blocking component. Unified mode
+        # charges the whole admission (prefill + insert) to the stall (the
+        # slots genuinely wait on it); two-pool mode charges only the
+        # handoff sync, the part a real two-pool deployment (prefill on its
+        # own devices) would retain. Single-host caveat: both pools share
+        # this process's device, so the stall split is the modeled
+        # decode-blocking time, while wall_s/ITL remain real measurements.
+        if disagg:
+            prefill_s = rec.total("prefill")
+            handoff_s = rec.total("scatter") + rec.total("bind")
+            decode_stall_s = rec.total("scatter", stall=True)
+        else:
+            prefill_s = rec.total("admission")
+            handoff_s = rec.total("insert")
+            decode_stall_s = rec.total("admission", stall=True)
+        decode_s = rec.total("decode_chunk")
         summary = scheduler.summary()
         if chunk_modes["approx"]:
             summary |= {"chunks_exact": chunk_modes["exact"],
@@ -1065,15 +1107,11 @@ class ServeEngine:
                     "decode_s": round(decode_s, 4),
                     "compile_s": round(compile_s, 4),
                     "wall_s": round(now(), 4),
-                    # per-phase utilization split (see the accounting
-                    # comment at the loop head): busy aliases keep the
-                    # disagg A/B readable next to the stall/handoff walls
-                    "prefill_busy_s": round(prefill_s, 4),
-                    "decode_busy_s": round(decode_s, 4),
                     "handoff_s": round(handoff_s, 4),
                     "decode_stall_s": round(decode_stall_s, 4),
                     "prefill_compiles": len(self._prefill_shapes),
                     "disagg": disagg}
+        summary |= rec.summary()
         if spec_chunks:
             summary |= {"spec_k": self.spec_k,
                         "spec_draft_layers": self.spec_draft_layers,

@@ -821,7 +821,6 @@ class SlotScheduler:
 
     def summary(self) -> dict:
         done = self.finished
-        ttfts = [r.ttft for r in done if r.ttft is not None]
         gen = sum(r.n_generated for r in done)
         out = {
             "requests": len(done),
@@ -842,12 +841,6 @@ class SlotScheduler:
             out["ready_depth_p50"] = float(np.percentile(rd, 50))
             out["ready_depth_p90"] = float(np.percentile(rd, 90))
             out["ready_depth_max"] = int(max(rd))
-        if ttfts:
-            out["ttft_mean_s"] = float(np.mean(ttfts))
-            out["ttft_max_s"] = float(np.max(ttfts))
-        rates = [r.decode_tok_s for r in done if r.decode_tok_s]
-        if rates:
-            out["decode_tok_s_mean_per_req"] = float(np.mean(rates))
         if any(r.tier != "premium" for r in done) or any(
                 m != "exact" for _, m in self.tier_mode_tokens):
             # tier section only when the stream actually used the knob

@@ -36,6 +36,7 @@ def make_train_step(cfg: ArchConfig, opt: AdamW, accum_steps: int = 1,
                               aux_weight=aux_weight)
         return loss, nll
 
+    @jax.named_scope("train_step")
     def train_step(state: TrainState, batch):
         params = state.params
 

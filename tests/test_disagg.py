@@ -73,8 +73,8 @@ def test_disagg_matches_unified(setup):
     assert on["disagg"] is True and off["disagg"] is False
     assert on["pages_leaked"] == 0 and off["pages_leaked"] == 0
     assert "ready_depth_p50" in on and "ready_depth_p50" not in off
-    assert {"prefill_busy_s", "decode_busy_s", "handoff_s",
-            "decode_stall_s"} <= set(on) & set(off)
+    assert {"prefill_s", "decode_s", "handoff_s",
+            "decode_stall_s", "spans"} <= set(on) & set(off)
 
 
 def test_disagg_prefix_cache_parity(setup):

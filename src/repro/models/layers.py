@@ -339,6 +339,31 @@ class PagedKVCache(NamedTuple):
     block_table: jax.Array  # (B, max_pages) int32 page ids, -1 = unmapped
 
 
+def at_layer(buf, layer):
+    """Superblock `layer` of a stacked cache leaf (leading axis: the
+    superblocks of `model.init_cache`)."""
+    return lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
+
+
+def set_layer(buf, value, layer):
+    """`buf` with superblock `layer` replaced by `value`."""
+    return lax.dynamic_update_index_in_dim(buf, value.astype(buf.dtype),
+                                           layer, 0)
+
+
+def _write_rows(cache, layer, idx, k, v, positions):
+    """Decode write: K/V/position rows at `idx` of superblock `layer`,
+    scattered straight into the stacked leaves (in place on a carried or
+    donated pool — no layer-sized copy is rebuilt around the write).
+    Returns (the updated stacked cache, its superblock-`layer` view)."""
+    at = (layer, *idx)
+    new = cache._replace(
+        k=cache.k.at[at].set(k.astype(cache.k.dtype)),
+        v=cache.v.at[at].set(v.astype(cache.v.dtype)),
+        positions=cache.positions.at[at].set(positions.astype(jnp.int32)))
+    return new, jax.tree.map(lambda a: at_layer(a, layer), new)
+
+
 def gather_pages(cache: PagedKVCache):
     """Gather each slot's mapped pages into a virtually-contiguous view.
 
@@ -421,12 +446,17 @@ def _pad_heads(q, k, v, kvh_target: int):
 def attention_block(x, p, cfg, meta, positions, cache: KVCache | None = None,
                     pos=None, rope: bool = True, causal: bool = True,
                     kv_override=None, prefix_len: int = 0,
-                    decode_multi: bool = False):
+                    decode_multi: bool = False, layer=None):
     """Full attention sub-layer. Returns (out, new_cache).
 
     meta: layer descriptor {"attn": "global"|"local"}. If `cache` is given and
     x is a single token, runs the decode path (ring-buffer update for local
-    layers). `kv_override` supplies cross-attention K/V source outputs.
+    layers). `cache` holds the whole stack (every leaf leads with the
+    superblock axis of `model.init_cache`) and `layer` is this superblock's
+    index: writes land at [layer, ...] of the stacked leaves and attention
+    reads the superblock-`layer` view, so a forward that carries the stack
+    through its layer scan updates it in place. `kv_override` supplies
+    cross-attention K/V source outputs.
     `prefix_len` (static) engages continued prefill: the dense cache's first
     `prefix_len` rows already hold KV for positions [0, prefix_len) — the
     serve engine's prefix-cache hits load them from shared pool pages — and
@@ -487,42 +517,35 @@ def attention_block(x, p, cfg, meta, positions, cache: KVCache | None = None,
         from repro.core import optflags
         from repro.core.precision import current_policy
         from repro.kernels import ops as K
+        b = jnp.arange(B)[:, None]
         if isinstance(cache, PagedKVCache):
-            psz = cache.k.shape[1]
-            P = cache.block_table.shape[1]
+            psz = cache.k.shape[-3]
+            P = cache.block_table.shape[-1]
             page_i = tpos // psz                        # (B, T)
             off = tpos % psz
-            b = jnp.arange(B)[:, None]
-            pid = cache.block_table[b, jnp.clip(page_i, 0, P - 1)]
+            pid = at_layer(cache.block_table,
+                           layer)[b, jnp.clip(page_i, 0, P - 1)]
             pid = jnp.where((page_i < P) & (pid >= 0), pid, 0)
-            k_c = cache.k.at[pid, off].set(k.astype(cache.k.dtype))
-            v_c = cache.v.at[pid, off].set(v.astype(cache.v.dtype))
-            pos_c = cache.positions.at[pid, off].set(tpos)
-            new_cache = PagedKVCache(k_c, v_c, pos_c, cache.block_table)
+            new_cache, lc = _write_rows(cache, layer, (pid, off), k, v, tpos)
             impl = optflags.decode_attn_impl()
             if impl == "fused" and K.fused_decode_supported(current_policy()):
-                btf = jnp.repeat(new_cache.block_table, T, axis=0)
+                btf = jnp.repeat(lc.block_table, T, axis=0)
                 o = K.paged_decode_attention(
-                    qf, new_cache.k, new_cache.v, new_cache.positions,
+                    qf, lc.k, lc.v, lc.positions,
                     btf, posf, window=window, cap=cfg.attn_softcap)
             else:
-                k_g, v_g, pos_g = gather_pages(new_cache)
+                k_g, v_g, pos_g = gather_pages(lc)
                 o = decode_attention(
                     qf, jnp.repeat(k_g, T, axis=0),
                     jnp.repeat(v_g, T, axis=0),
                     jnp.repeat(pos_g, T, axis=0), posf, window=window,
                     cap=cfg.attn_softcap)
         else:
-            S = cache.k.shape[1]
-            slot = tpos % S                             # (B, T)
-            b = jnp.arange(B)[:, None]
-            k_c = cache.k.at[b, slot].set(k.astype(cache.k.dtype))
-            v_c = cache.v.at[b, slot].set(v.astype(cache.v.dtype))
-            pos_c = cache.positions.at[b, slot].set(tpos)
-            new_cache = KVCache(k_c, v_c, pos_c)
+            slot = tpos % cache.k.shape[-3]             # (B, T)
+            new_cache, lc = _write_rows(cache, layer, (b, slot), k, v, tpos)
             o = decode_attention(
-                qf, jnp.repeat(k_c, T, axis=0), jnp.repeat(v_c, T, axis=0),
-                jnp.repeat(pos_c, T, axis=0), posf, window=window,
+                qf, jnp.repeat(lc.k, T, axis=0), jnp.repeat(lc.v, T, axis=0),
+                jnp.repeat(lc.positions, T, axis=0), posf, window=window,
                 cap=cfg.attn_softcap)
         o = o.reshape(B, T, H, hd)
     elif (cache is not None and x.shape[1] == 1
@@ -533,17 +556,15 @@ def attention_block(x, p, cfg, meta, positions, cache: KVCache | None = None,
         # inside a chunk) write to the reserved trash page 0 instead —
         # never to a page another request owns.
         B = x.shape[0]
-        psz = cache.k.shape[1]
-        P = cache.block_table.shape[1]
+        psz = cache.k.shape[-3]
+        P = cache.block_table.shape[-1]
         page_i = (pos // psz).astype(jnp.int32)         # (B,)
         off = (pos % psz).astype(jnp.int32)
         b = jnp.arange(B)
-        pid = cache.block_table[b, jnp.clip(page_i, 0, P - 1)]
+        pid = at_layer(cache.block_table, layer)[b, jnp.clip(page_i, 0, P - 1)]
         pid = jnp.where((page_i < P) & (pid >= 0), pid, 0)
-        k_c = cache.k.at[pid, off].set(k[:, 0].astype(cache.k.dtype))
-        v_c = cache.v.at[pid, off].set(v[:, 0].astype(cache.v.dtype))
-        pos_c = cache.positions.at[pid, off].set(pos.astype(jnp.int32))
-        new_cache = PagedKVCache(k_c, v_c, pos_c, cache.block_table)
+        new_cache, lc = _write_rows(cache, layer, (pid, off), k[:, 0],
+                                    v[:, 0], pos)
         # attention over the slot's mapped pages only; page order in the
         # block table is allocation order == sequence order, so the paged
         # view is position-sorted exactly like a non-wrapped ring. Default
@@ -558,26 +579,21 @@ def attention_block(x, p, cfg, meta, positions, cache: KVCache | None = None,
         impl = optflags.decode_attn_impl()
         if impl == "fused" and K.fused_decode_supported(current_policy()):
             o = K.paged_decode_attention(
-                q, new_cache.k, new_cache.v, new_cache.positions,
-                new_cache.block_table, pos, window=window,
-                cap=cfg.attn_softcap)
+                q, lc.k, lc.v, lc.positions, lc.block_table, pos,
+                window=window, cap=cfg.attn_softcap)
         else:
-            k_g, v_g, pos_g = gather_pages(new_cache)
+            k_g, v_g, pos_g = gather_pages(lc)
             o = decode_attention(q, k_g, v_g, pos_g, pos, window=window,
                                  cap=cfg.attn_softcap)
     elif cache is not None and x.shape[1] == 1:
         # per-slot ring write: row b of the batch is an independent request
         # at its own depth, so each row scatters into its own ring slot
-        B = x.shape[0]
-        S = cache.k.shape[1]
-        slot = (pos % S).astype(jnp.int32)              # (B,)
-        b = jnp.arange(B)
-        k_c = cache.k.at[b, slot].set(k[:, 0].astype(cache.k.dtype))
-        v_c = cache.v.at[b, slot].set(v[:, 0].astype(cache.v.dtype))
-        pos_c = cache.positions.at[b, slot].set(pos.astype(jnp.int32))
-        new_cache = KVCache(k_c, v_c, pos_c)
-        o = decode_attention(q, k_c, v_c, pos_c, pos, window=window,
-                             cap=cfg.attn_softcap)
+        slot = (pos % cache.k.shape[-3]).astype(jnp.int32)   # (B,)
+        new_cache, lc = _write_rows(cache, layer, (jnp.arange(x.shape[0]),
+                                                   slot), k[:, 0], v[:, 0],
+                                    pos)
+        o = decode_attention(q, lc.k, lc.v, lc.positions, pos,
+                             window=window, cap=cfg.attn_softcap)
     else:
         from repro.core import optflags
         k_att, v_att = k, v
@@ -591,9 +607,11 @@ def attention_block(x, p, cfg, meta, positions, cache: KVCache | None = None,
             # cache of values the SA contract re-quantizes to bf16 anyway),
             # so the suffix rows come out bit-identical to full prefill.
             k_att = jnp.concatenate(
-                [cache.k[:, :prefix_len].astype(k.dtype), k], axis=1)
+                [at_layer(cache.k, layer)[:, :prefix_len].astype(k.dtype),
+                 k], axis=1)
             v_att = jnp.concatenate(
-                [cache.v[:, :prefix_len].astype(v.dtype), v], axis=1)
+                [at_layer(cache.v, layer)[:, :prefix_len].astype(v.dtype),
+                 v], axis=1)
         if cache is not None and optflags.enabled("pallas_attention"):
             # serving prefill (full or continued) is forward-only: use the
             # Pallas flash kernel (VMEM-resident softmax state;
@@ -618,32 +636,31 @@ def attention_block(x, p, cfg, meta, positions, cache: KVCache | None = None,
                 raise ValueError(
                     "paged KV caches take prefill via the engine's fragment "
                     "splice, not a multi-token forward")
-            S = cache.k.shape[1]
+            B, S = cache.positions.shape[-2:]
             T = k.shape[1]
-            k = k.astype(cache.k.dtype)
-            v = v.astype(cache.v.dtype)
             if T >= S:                   # keep last S positions (ring)
                 assert not prefix_len, (
                     "continued prefill needs prefix_len + suffix <= cache "
                     "capacity (the engine sizes fragments to whole prompts)")
-                bidx = jnp.arange(k.shape[0])[:, None]
-                k_keep, v_keep = k[:, -S:], v[:, -S:]
+                bidx = jnp.arange(B)[:, None]
                 pos_keep = positions[:, -S:].astype(jnp.int32)   # (B, S)
                 # ring layout: slot = pos % S, per batch row
                 slots = pos_keep % S
-                k_c = jnp.zeros_like(cache.k).at[bidx, slots].set(k_keep)
-                v_c = jnp.zeros_like(cache.v).at[bidx, slots].set(v_keep)
-                pos_c = (jnp.full_like(cache.positions, -1)
-                         .at[bidx, slots].set(pos_keep))
+                lc = KVCache(
+                    jnp.zeros(cache.k.shape[1:], cache.k.dtype)
+                    .at[bidx, slots].set(k[:, -S:].astype(cache.k.dtype)),
+                    jnp.zeros(cache.v.shape[1:], cache.v.dtype)
+                    .at[bidx, slots].set(v[:, -S:].astype(cache.v.dtype)),
+                    jnp.full((B, S), -1, jnp.int32)
+                    .at[bidx, slots].set(pos_keep))
+                new_cache = jax.tree.map(
+                    lambda full, new: set_layer(full, new, layer), cache, lc)
             else:                        # suffix rows land after the prefix
-                k_c = lax.dynamic_update_slice_in_dim(
-                    cache.k, k, prefix_len, axis=1)
-                v_c = lax.dynamic_update_slice_in_dim(
-                    cache.v, v, prefix_len, axis=1)
-                pos_c = lax.dynamic_update_slice_in_dim(
-                    cache.positions, positions.astype(jnp.int32), prefix_len,
-                    axis=1)
-            new_cache = KVCache(k_c, v_c, pos_c)
+                new_cache = KVCache(*(
+                    lax.dynamic_update_slice(
+                        full, new[None].astype(full.dtype),
+                        (layer, 0, prefix_len) + (0,) * (full.ndim - 3))
+                    for full, new in zip(cache, (k, v, positions))))
     o = o[:, :, :H_orig]   # drop padded q-head outputs before the projection
     return attn_out(o, p), new_cache
 

@@ -245,38 +245,34 @@ def init_cache(cfg: ArchConfig, batch: int, seq_len: int,
 # ---------------------------------------------------------------------------
 
 def _sublayer(x, p, cfg, meta, positions, cache, pos, encoder_out,
-              prefix_len: int = 0, decode_multi: bool = False):
-    """One transformer layer. Returns (x, new_cache)."""
-    new_cache: dict[str, Any] = {}
+              prefix_len: int = 0, decode_multi: bool = False, layer=None):
+    """One transformer layer. Returns (x, new_cache).
+
+    `cache` holds the whole stack's leaves for this sublayer (leading
+    superblock axis) and `layer` is the superblock's index: every write
+    lands at [layer, ...] of the stacked leaves, and leaves the layer does
+    not write pass through."""
+    new_cache: dict[str, Any] = dict(cache) if cache is not None else {}
     h = L.norm_apply(x, p["norm1"], cfg.norm, cfg.norm_eps)
     if cfg.family == "ssm":
-        ssm_cache = cache.get("ssm") if cache else None
-        mix, st = mamba2_block(h, p["ssm"], cfg,
-                               state=ssm_cache[0] if ssm_cache else None,
-                               conv_cache=ssm_cache[1] if ssm_cache else None)
-        if cache is not None:
-            new_cache["ssm"] = st
+        mix, new_cache["ssm"] = _ssm_mix(h, p["ssm"], cfg, cache, layer)
         return x + mix.astype(x.dtype), (new_cache if cache is not None else None)
+    kv_cache = cache.get("kv") if cache else None
     if cfg.hybrid:
         a, kv = L.attention_block(h, p["attn"], cfg, meta, positions,
-                                  cache=cache.get("kv") if cache else None,
-                                  pos=pos, prefix_len=prefix_len,
-                                  decode_multi=decode_multi)
-        ssm_cache = cache.get("ssm") if cache else None
-        s, st = mamba2_block(h, p["ssm"], cfg,
-                             state=ssm_cache[0] if ssm_cache else None,
-                             conv_cache=ssm_cache[1] if ssm_cache else None)
+                                  cache=kv_cache, pos=pos,
+                                  prefix_len=prefix_len,
+                                  decode_multi=decode_multi, layer=layer)
+        s, new_cache["ssm"] = _ssm_mix(h, p["ssm"], cfg, cache, layer)
         mix = 0.5 * (L.rmsnorm(a, p["attn_norm"]["w"], cfg.norm_eps)
                      + L.rmsnorm(s, p["ssm_norm"]["w"], cfg.norm_eps))
-        if cache is not None:
-            new_cache |= {"kv": kv, "ssm": st}
     else:
         mix, kv = L.attention_block(h, p["attn"], cfg, meta, positions,
-                                    cache=cache.get("kv") if cache else None,
-                                    pos=pos, prefix_len=prefix_len,
-                                    decode_multi=decode_multi)
-        if cache is not None:
-            new_cache["kv"] = kv
+                                    cache=kv_cache, pos=pos,
+                                    prefix_len=prefix_len,
+                                    decode_multi=decode_multi, layer=layer)
+    if cache is not None:
+        new_cache["kv"] = kv
     x = x + mix.astype(x.dtype)
     if cfg.is_encdec and encoder_out is not None:
         h = L.norm_apply(x, p["norm_cross"], cfg.norm, cfg.norm_eps)
@@ -285,8 +281,6 @@ def _sublayer(x, p, cfg, meta, positions, cache, pos, encoder_out,
             cache=None, rope=False, causal=False,
             kv_override=_encoder_kv(p["cross"], cfg, encoder_out))
         x = x + ca.astype(x.dtype)
-        if cache is not None:
-            new_cache["cross"] = cache.get("cross")
     h = L.norm_apply(x, p["norm2"], cfg.norm, cfg.norm_eps)
     aux = None
     if meta["moe"]:
@@ -302,6 +296,16 @@ def _sublayer(x, p, cfg, meta, positions, cache, pos, encoder_out,
     else:
         f = L.ffn_swiglu(h, p["ffn"], cfg.act)
     return x + f.astype(x.dtype), (new_cache if cache is not None else aux)
+
+
+def _ssm_mix(h, p, cfg, cache, layer):
+    """Mamba2 mixer on superblock `layer` of a stacked ssm/conv state.
+    Returns (mix, the updated stacked state, or None without a cache)."""
+    ssm = cache.get("ssm") if cache else None
+    if ssm is None:
+        return mamba2_block(h, p, cfg)[0], None
+    mix, st = mamba2_block(h, p, cfg, *(L.at_layer(a, layer) for a in ssm))
+    return mix, tuple(L.set_layer(a, new, layer) for a, new in zip(ssm, st))
 
 
 def _encoder_kv(p, cfg, encoder_out):
@@ -394,9 +398,15 @@ def forward(params, cfg: ArchConfig, tokens, *, positions=None, cache=None,
             positions = jnp.broadcast_to(jnp.arange(T), (B, T))
 
     period = cfg.stack_period
+    n_super = jax.tree.leaves(params["layers"])[0].shape[0]
 
-    def superblock(x, xs):
-        p_sb, cache_sb = xs
+    def superblock(carry, xs):
+        # the cache rides in the carry, not in xs/ys: each layer writes its
+        # rows in place at [i, ...] of the stacked leaves (a decode step's
+        # B rows scatter into the donated pool) instead of slicing its
+        # layer out and restacking a fresh copy of the whole cache
+        x, cache = carry
+        p_sb, i = xs
         x = S_.constrain(x, "batch", None, None)  # pin the residual stream
         from repro.core import optflags
         if optflags.enabled("bf16_params_in_layers"):
@@ -411,24 +421,25 @@ def forward(params, cfg: ArchConfig, tokens, *, positions=None, cache=None,
         new_caches = []
         aux_acc = jnp.zeros((2,), jnp.float32)
         for j in range(period):
-            c_j = None if cache_sb is None else cache_sb[j]
+            c_j = None if cache is None else cache[j]
             x, extra = _sublayer(x, p_sb[j], cfg, cfg.layer_kind(j),
                                  positions, c_j, pos, encoder_out,
-                                 prefix_len, decode_multi)
-            if cache_sb is not None:
+                                 prefix_len, decode_multi, layer=i)
+            if cache is not None:
                 new_caches.append(extra)
             elif isinstance(extra, dict):   # moe aux losses
                 aux_acc = aux_acc + jnp.stack(
                     [extra["load_balance"], extra["router_z"]])
-        return x, (tuple(new_caches) if cache_sb is not None else None,
-                   aux_acc)
+        return (x, tuple(new_caches) if cache is not None else None), aux_acc
 
     if cfg.remat and cache is None:   # remat for training only
         superblock = jax.checkpoint(
             superblock, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-    cache_xs = cache if cache is not None else None
-    x, (new_cache, aux_sb) = lax.scan(
-        superblock, x, (params["layers"], cache_xs))
+    # the scan runs the superblocks of `params` — a prefix of the cache's
+    # stack when a caller passes fewer layers (the early-exit draft)
+    (x, new_cache), aux_sb = lax.scan(
+        superblock, (x, cache),
+        (params["layers"], jnp.arange(n_super, dtype=jnp.int32)))
     aux = {"load_balance": jnp.sum(aux_sb[:, 0]),
            "router_z": jnp.sum(aux_sb[:, 1])}
     x = L.norm_apply(x, params["final_norm"], cfg.norm, cfg.norm_eps)

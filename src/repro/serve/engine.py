@@ -154,7 +154,11 @@ class ServeEngine:
             for j in range(cfg.stack_period))
         self._frag_floor = (cfg.window if has_local and cfg.window
                             and cfg.window < self.max_seq_len else 1)
-        self._prefill = _jit("prefill", make_prefill_step(cfg))
+        # prefills take a fresh fragment (argument 2) and write it in
+        # place: the layer scan carries the cache (model.forward), and an
+        # undonated fragment would be copied into the carry first
+        self._prefill = _jit("prefill", make_prefill_step(cfg),
+                             donate_argnums=(2,))
         # continued-prefill variants, one jitted closure per shared-prefix
         # length (prefix_len is trace-time state like the arithmetic mode);
         # drivers already quantize prompt lengths, and shared spans are
@@ -317,33 +321,32 @@ class ServeEngine:
         def splice(full, one):
             if not isinstance(full, PagedKVCache):
                 return full
-            n_super, n_pages, psz = full.k.shape[:3]
+            psz = full.k.shape[2]
             s_frag = one.k.shape[2]
             npp = s_frag // psz
-            lane = jnp.arange(psz, dtype=jnp.int32)
             dest_row = jnp.where(jnp.arange(npp) < keep, 0,
                                  block_row[:npp])
             # bucketed prefill fragments can round up past the allocated
             # run (-1 tail in block_row): those pages hold pure padding
             # (positions already -1), redirect them to the trash page
             dest_row = jnp.where(dest_row >= 0, dest_row, 0)
-            dest = (dest_row[:, None] * psz + lane).reshape(-1)
             wipe_row = jnp.where(block_row >= 0, block_row, 0)
             wipe_row = jnp.where(
-                jnp.arange(block_row.shape[0]) < keep, 0, wipe_row)
-            wipe = (wipe_row[:, None]
-                    * psz + lane).reshape(-1)   # page 0 wipe: harmless
-            kf = full.k.reshape(n_super, n_pages * psz, *full.k.shape[3:])
-            vf = full.v.reshape(n_super, n_pages * psz, *full.v.shape[3:])
-            pf = full.positions.reshape(n_super, n_pages * psz)
-            kf = kf.at[:, dest].set(one.k[:, 0].astype(kf.dtype))
-            vf = vf.at[:, dest].set(one.v[:, 0].astype(vf.dtype))
-            pf = pf.at[:, wipe].set(-1)
-            pf = pf.at[:, dest].set(one.positions[:, 0])
-            return PagedKVCache(kf.reshape(full.k.shape),
-                                vf.reshape(full.v.shape),
-                                pf.reshape(full.positions.shape),
-                                full.block_table)
+                jnp.arange(block_row.shape[0]) < keep, 0,
+                wipe_row)                       # page 0 wipe: harmless
+
+            def pages(x):           # (n_super, 1, s_frag, ...) fragment
+                return x[:, 0].reshape(x.shape[0], npp, psz, *x.shape[3:])
+
+            # whole pages go to [:, page] of the pool as it is laid out: a
+            # flattened (page · offset) view makes XLA relayout the whole
+            # pool around the scatter, on the way in and out
+            return PagedKVCache(
+                full.k.at[:, dest_row].set(pages(one.k).astype(full.k.dtype)),
+                full.v.at[:, dest_row].set(pages(one.v).astype(full.v.dtype)),
+                full.positions.at[:, wipe_row].set(-1)
+                .at[:, dest_row].set(pages(one.positions)),
+                full.block_table)
 
         return jax.tree.map(
             splice, cache, frag,
@@ -418,14 +421,16 @@ class ServeEngine:
         def load(one, full):
             if not isinstance(full, PagedKVCache):
                 return one
-            n_super, _, psz = full.k.shape[:3]
-            lane = jnp.arange(psz, dtype=jnp.int32)
-            src = (src_row[:, None] * psz + lane).reshape(-1)[:prefix_len]
-            kf = full.k.reshape(n_super, -1, *full.k.shape[3:])[:, src]
-            vf = full.v.reshape(n_super, -1, *full.v.shape[3:])[:, src]
+            def rows(x):            # whole source pages, then the rows
+                x = x[:, src_row]
+                return x.reshape(x.shape[0], -1,
+                                 *x.shape[3:])[:, :prefix_len]
+
             return KVCache(
-                one.k.at[:, 0, :prefix_len].set(kf.astype(one.k.dtype)),
-                one.v.at[:, 0, :prefix_len].set(vf.astype(one.v.dtype)),
+                one.k.at[:, 0, :prefix_len].set(
+                    rows(full.k).astype(one.k.dtype)),
+                one.v.at[:, 0, :prefix_len].set(
+                    rows(full.v).astype(one.v.dtype)),
                 one.positions.at[:, 0, :prefix_len].set(
                     jnp.arange(prefix_len, dtype=jnp.int32)))
 
@@ -437,7 +442,8 @@ class ServeEngine:
         """Jitted prefill closure for one static shared-prefix length."""
         fn = self._prefills.get(prefix_len)
         if fn is None:
-            fn = _jit("prefill", make_prefill_step(self.cfg, prefix_len))
+            fn = _jit("prefill", make_prefill_step(self.cfg, prefix_len),
+                      donate_argnums=(2,))
             self._prefills[prefix_len] = fn
         return fn
 
@@ -448,7 +454,8 @@ class ServeEngine:
         fn = self._bucketed_prefills.get(prefix_len)
         if fn is None:
             fn = _jit("bucketed_prefill",
-                      make_bucketed_prefill_step(self.cfg, prefix_len))
+                      make_bucketed_prefill_step(self.cfg, prefix_len),
+                      donate_argnums=(2,))
             self._bucketed_prefills[prefix_len] = fn
         return fn
 

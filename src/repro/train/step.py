@@ -163,21 +163,19 @@ def make_draft_step(cfg: ArchConfig, draft_layers: int):
     no second model — then the shared final norm + lm_head.
 
     (params, token (B,1), cache, pos (B,)) → (logits (B,1,V), new_cache).
-    The returned cache merges the draft's early-superblock KV writes back
-    into the full-depth cache tree: consecutive draft steps must see each
-    other's keys, and the verify forward later overwrites every position
-    the draft wrote (all layers, pos..pos+k ⊇ early layers, pos..pos+k-1),
-    so a rejected draft leaves no live state behind.
+    The forward runs the first `draft_layers` superblocks over the
+    full-depth cache tree and writes their KV in place: consecutive draft
+    steps must see each other's keys, and the verify forward later
+    overwrites every position the draft wrote (all layers, pos..pos+k ⊇
+    early layers, pos..pos+k-1), so a rejected draft leaves no live state
+    behind.
     """
     E = draft_layers
 
     def draft_step(params, token, cache, pos):
         p = dict(params)
         p["layers"] = jax.tree.map(lambda x: x[:E], params["layers"])
-        sub = jax.tree.map(lambda x: x[:E], cache)
-        logits, new_sub, _ = M.forward(p, cfg, token, cache=sub, pos=pos)
-        cache = jax.tree.map(lambda full, new: full.at[:E].set(new),
-                             cache, new_sub)
+        logits, cache, _ = M.forward(p, cfg, token, cache=cache, pos=pos)
         return logits, cache
 
     return draft_step

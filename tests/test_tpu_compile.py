@@ -9,6 +9,8 @@ kernel is in the compiled module. The topology is described inside a
 fixture (never at import): only one process may load libtpu, and pytest
 workers import every test file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -98,3 +100,114 @@ def test_sa_matmul_compiles_at_full_width(one_chip):
                                       interpret=False),
         s((m, k)), s((k, n)))
     assert "tpu_custom_call" in text
+
+
+def _while_body_instructions(text):
+    """The instruction lines of every computation a while body reaches
+    (the body itself, the fusions and calls inside it, nested loops)."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif line == "}":
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    callee = r"(?:body|condition|calls|to_apply)=%([\w.\-]+)"
+    todo = [c for lines in comps.values() for line in lines
+            for c in re.findall(r"body=%([\w.\-]+)", line)]
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c not in seen and c in comps:
+            seen.add(c)
+            todo += [d for line in comps[c] for d in re.findall(callee, line)]
+    return [line for c in seen for line in comps[c]]
+
+
+def _phi3_10l_serving(one_chip, monkeypatch):
+    """phi3-medium-10l's serving shapes in the `phi3-10l-longdoc` cell: the
+    config, bf16 weights and the fp32 page pool (8 slots, 1,025 pages of
+    16, max_seq_len 2048), placed on the described chip; Mosaic kernels."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models import model as M
+
+    monkeypatch.setattr(ops, "INTERPRET", False)
+    cfg = dataclasses.replace(get_config("phi3-medium-14b"), num_layers=10,
+                              vocab_size=32064)
+    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip)
+    params = jax.tree.map(place, M.abstract_params(cfg, jnp.bfloat16))
+    pool = jax.tree.map(place, M.init_cache(
+        cfg, 8, 2048, dtype=jnp.float32, abstract=True, paged=(1025, 16)))
+    return cfg, params, pool, place
+
+
+def _pool_shaped(lines, k, ops):
+    """Names of the ops in `lines` that output K's whole stacked shape."""
+    dims = ",".join(map(str, k.shape))
+    return [line.split(" = ")[0].strip() for line in lines
+            if re.search(rf"= f32\[{dims}\]\S* ({ops})\(", line)]
+
+
+def test_decode_chunk_updates_the_pool_in_place(one_chip, monkeypatch):
+    """The 8-step decode chunk at phi3-medium-10l's serving shape, pool
+    donated: no op inside the step or layer loops rebuilds the stacked K
+    or V pool (a `copy` or `dynamic-update-slice` of its whole shape) —
+    the layer scan carries the pool and scatters each step's rows into it
+    — and the chunk's temporaries stay below two stacked K+V pools (a
+    restacked copy per step would add at least one more)."""
+    from jax import lax
+
+    from repro.train.step import make_serve_step
+
+    cfg, params, pool, place = _phi3_10l_serving(one_chip, monkeypatch)
+    B = pool[0]["kv"].block_table.shape[1]
+    vec = place(jax.ShapeDtypeStruct((B,), jnp.int32))
+    serve_step = make_serve_step(cfg)
+
+    def chunk(params, tok, cache, pos):
+        def body(carry, _):
+            tok, cache, pos = carry
+            logits, cache = serve_step(params, tok[:, None], cache, pos)
+            nxt = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
+            return (nxt, cache, pos + 1), nxt
+
+        return lax.scan(body, (tok, cache, pos), length=8)
+
+    compiled = jax.jit(chunk, donate_argnums=(2,)).lower(
+        params, vec, pool, vec).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    k = pool[0]["kv"].k
+    assert _pool_shaped(_while_body_instructions(text), k,
+                        "copy|dynamic-update-slice") == []
+    kv_pool_bytes = 2 * k.size * jnp.dtype(k.dtype).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * kv_pool_bytes
+
+
+def test_admission_scatters_into_the_pool_in_place(one_chip, monkeypatch):
+    """`_insert` of a 2048-token prefill fragment into the donated pool at
+    the same shape writes whole pages where the pool lies: no op copies
+    the stacked pool (a flattened page·offset view needs a relayout of the
+    whole pool, in and out) and the program needs no temporaries of its
+    size."""
+    from repro.models import model as M
+    from repro.serve.engine import ServeEngine
+
+    cfg, _, pool, place = _phi3_10l_serving(one_chip, monkeypatch)
+    frag = jax.tree.map(place, M.init_cache(cfg, 1, 2048, dtype=jnp.float32,
+                                            abstract=True))
+    i32 = lambda *shape: place(jax.ShapeDtypeStruct(shape, jnp.int32))
+    compiled = jax.jit(ServeEngine._insert_impl, donate_argnums=(0,)).lower(
+        pool, frag, i32(), i32(pool[0]["kv"].block_table.shape[2]),
+        i32()).compile()
+    k = pool[0]["kv"].k
+    assert _pool_shaped(compiled.as_text().splitlines(), k, "copy") == []
+    layer_bytes = k.size // k.shape[0] * jnp.dtype(k.dtype).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes

@@ -1,7 +1,9 @@
 """Operations and bytes of the work a cell asks for, from published sizes.
 
 Counts follow the configuration file (`weights.sizes`), never the
-program's own counters, so that no change to the program can move them:
+program's own counters, so that no change to the program can move them.
+Each count is the family's (`bench/families/<model_type>.py`, picked by
+`s["model_type"]`); the decoder families count so:
 
 * a forward token costs 2 FLOPs per weight it multiplies, counting only
   the `k` experts the router picks (never the ones a dropless dispatch
@@ -16,6 +18,8 @@ from __future__ import annotations
 import json
 from pathlib import Path
 
+from bench.families import family
+
 ROOT = Path(__file__).resolve().parent
 
 
@@ -28,19 +32,10 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def layer_weights(s: dict) -> int:
-    """Weights one token multiplies in one layer."""
-    d, H, KVH, hd, F = s["d"], s["H"], s["KVH"], s["hd"], s["F"]
-    attn = d * H * hd + 2 * d * KVH * hd + H * hd * d
-    if s["E"]:
-        return attn + d * s["E"] + s["k"] * 3 * d * F
-    return attn + 3 * d * F
-
-
 def attention_flops(s: dict, pairs: int) -> int:
     """Score and value products over `pairs` (query, visible key) pairs,
     all layers."""
-    return s["L"] * 4 * s["H"] * s["hd"] * pairs
+    return family(s["model_type"]).attention_flops(s, pairs)
 
 
 def causal_pairs(T: int, start: int = 0) -> int:
@@ -50,27 +45,24 @@ def causal_pairs(T: int, start: int = 0) -> int:
 
 
 def prefill_flops(s: dict, T: int) -> int:
-    """A prompt of T tokens: every layer on every token, causal attention,
-    the LM head on the last row only (the first generated token)."""
-    return (2 * T * s["L"] * layer_weights(s) + 2 * s["d"] * s["V"]
-            + attention_flops(s, causal_pairs(T)))
+    """A prompt of T tokens, and the first generated token."""
+    return family(s["model_type"]).prefill_flops(s, T)
 
 
 def decode_flops(s: dict, ctx: int) -> int:
     """One generated token whose input sits at position `ctx` (it attends
     to ctx + 1 keys)."""
-    return (2 * (s["L"] * layer_weights(s) + s["d"] * s["V"])
-            + attention_flops(s, ctx + 1))
+    return family(s["model_type"]).decode_flops(s, ctx)
 
 
 def decode_kv_bytes(s: dict, ctx: int, kv_bytes: int) -> int:
     """K and V read by one decode step of one slot at position `ctx`."""
-    return s["L"] * 2 * s["KVH"] * s["hd"] * kv_bytes * (ctx + 1)
+    return family(s["model_type"]).decode_kv_bytes(s, ctx, kv_bytes)
 
 
 def prefill_attention_bytes(s: dict, T: int, in_bytes: int = 2,
                             out_bytes: int = 4) -> int:
     """Least bytes of a causal prefill's attention, all layers: q, k, v
     read once, the output written once."""
-    return s["L"] * T * s["hd"] * ((s["H"] + 2 * s["KVH"]) * in_bytes
-                                   + s["H"] * out_bytes)
+    return family(s["model_type"]).prefill_attention_bytes(
+        s, T, in_bytes, out_bytes)

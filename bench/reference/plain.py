@@ -1,15 +1,12 @@
-"""The plain reference: the decoder's forward pass in float32 jax.numpy.
+"""The plain reference: a model's forward pass in float32 jax.numpy.
 
-It follows the published description of the two families the benchmark
-runs (Llama-style dense GQA with RoPE and SwiGLU; granite's sparse-expert
-FFN with softmax top-k routing renormalised over the chosen experts, and
-its four scalar multipliers: embeddings times `embedding_multiplier`,
-attention scores times `attention_multiplier`, each residual branch times
-`residual_multiplier`, logits divided by `logits_scaling`). It imports
-nothing of the program: it reads the benchmark's own weights
-(`bench/weights.py` layout), each times its `published_scale`, and the
-configuration file. Sliding windows are not modelled: a cell's sequences
-stay within its configuration's window (bench/cell.py checks it).
+Each family file (`bench/families/<model_type>.py`) gives the published
+function of its architecture as `logits()`, built from the blocks here:
+RMSNorm, RoPE, causal GQA softmax attention, SwiGLU and a softmax top-k
+expert FFN renormalised over the chosen experts. `logits()` here picks the
+family by the sizes' `model_type`. None of it imports the program: it
+reads the benchmark's own weights (`bench/weights.py`) and the
+configuration file.
 
 No cache, no kernels, no batching: one sequence at a time, every matrix
 product at `Precision.HIGHEST` on float32 values. Layers run one after
@@ -22,8 +19,6 @@ rounded to float8 e4m3, scaled per row of the left operand and per column
 of the right one over the contracted axis, still accumulating in float32.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -113,53 +108,19 @@ def _moe(h, p, k: int, fp8: bool, wd_scale: float):
 
 
 def static_sizes(sz: dict) -> tuple:
-    """`weights.sizes()` with its `published_scale`, hashable, for
-    `logits(s=...)`."""
-    from bench.weights import published_scale
-
-    return tuple(sorted(dict(sz, scale=tuple(sorted(
-        published_scale(sz).items()))).items()))
+    """`weights.sizes()`, hashable, for `logits(s=...)`."""
+    return tuple(sorted(sz.items()))
 
 
-@functools.partial(jax.jit, static_argnames=("s", "fp8", "block"))
 def logits(weights, tokens, *, s: tuple, fp8: bool = False,
            block: int = 512):
     """tokens (T,) int32, T a multiple of `block` → logits (T, Vp) float32,
-    padding columns of the vocabulary at -inf. `s` is `key(sizes)`."""
-    s = dict(s)
-    H, KVH, hd = s["H"], s["KVH"], s["hd"]
-    sc = dict(s["scale"])
-    T = tokens.shape[0]
-    x = s["m_emb"] * weights["embed"][tokens].astype(jnp.float32)
+    padding columns of the vocabulary at -inf, by the family of `s`
+    (`static_sizes(sizes)`)."""
+    from bench.families import family
 
-    def w(leaf, name):
-        return leaf.astype(jnp.float32) * sc.get(name, 1.0)
-
-    def layer(x, p):
-        h = _rmsnorm(x, p["norm1"]["w"], s["eps"])
-        a = p["attn"]
-        q = _rope(_einsum("td,dn->tn", h, w(a["wq"], "wq"), fp8)
-                  .reshape(T, H, hd), s["theta"])
-        k = _rope(_einsum("td,dn->tn", h, a["wk"], fp8).reshape(T, KVH, hd),
-                  s["theta"])
-        v = _einsum("td,dn->tn", h, a["wv"], fp8).reshape(T, KVH, hd)
-        o = _attention(q, k, v, fp8, block, s["m_att"]).reshape(T, H * hd)
-        x = x + s["m_res"] * _einsum("tn,nd->td", o, w(a["wo"], "wo"), fp8)
-        h = _rmsnorm(x, p["norm2"]["w"], s["eps"])
-        hb = h.reshape(T // block, block, -1)
-        if "moe" in p:
-            f = lax.map(lambda hi: _moe(hi, p["moe"], s["k"], fp8,
-                                        sc.get("wd", 1.0)), hb)
-        else:
-            f = lax.map(lambda hi: _swiglu(hi, p["ffn"]["wg"], p["ffn"]["wu"],
-                                           w(p["ffn"]["wd"], "wd"), fp8), hb)
-        return x + s["m_res"] * f.reshape(T, -1), None
-
-    x, _ = lax.scan(layer, x, weights["layers"][0])
-    x = _rmsnorm(x, w(weights["final_norm"]["w"], "final_norm"), s["eps"])
-    head = (weights["embed"].T if s["tied"] else weights["lm_head"])
-    out = _einsum("td,dv->tv", x, head, fp8) / s["m_logit"]
-    return jnp.where(jnp.arange(s["Vp"]) < s["V"], out, -jnp.inf)
+    return family(dict(s)["model_type"]).logits(weights, tokens, s=s,
+                                                fp8=fp8, block=block)
 
 
 def served_gaps(weights, sz: dict, prompt, served, pad_to: int,

@@ -2,8 +2,9 @@
 
 What the benchmark takes from the program is listed in PERF.md as its
 contract with it: `ArchConfig`, `ServeEngine(...)`, `engine.serve`,
-`engine.new_pool` (shapes only, through `jax.eval_shape`), the engine's
-prompt buckets (`_bucket_len`),
+`engine.new_pool` (shapes only, through `jax.eval_shape`; its
+`PagedKVCache` leaves `k` and `v`), the engine's prompt buckets
+(`_bucket_len`),
 `SlotScheduler` (`submit`, `admit`, `start`, `observe`, `next_arrival`,
 `drained`, `slots`) and the `Request` fields (`rid`, `prompt`, `tokens`,
 `arrival_time`, `t_admitted`, `t_first_token`, `finish_reason`).
@@ -151,12 +152,19 @@ def make_engine(cfg, params, eng: dict):
 
 
 def kv_bytes(engine) -> int:
-    """Bytes of one K or V value in the page pool this engine builds."""
-    shapes = jax.eval_shape(engine.new_pool)
-    sizes = {jax.numpy.dtype(x.dtype).itemsize
-             for x in jax.tree.leaves(shapes) if len(x.shape) == 5}
+    """Bytes of one K or V value in the page pool this engine builds: its
+    `PagedKVCache` K and V pages, whatever state sits beside them."""
+    from repro.models.layers import PagedKVCache
+
+    pages = [x for x in jax.tree.leaves(
+        jax.eval_shape(engine.new_pool),
+        is_leaf=lambda x: isinstance(x, PagedKVCache))
+        if isinstance(x, PagedKVCache)]
+    sizes = {jax.numpy.dtype(a.dtype).itemsize for p in pages
+             for a in (p.k, p.v)}
     if len(sizes) != 1:
-        raise ValueError(f"page pool leaves of several dtypes: {sizes}")
+        raise ValueError("the page pool's K and V pages have dtypes of "
+                         f"{sizes} bytes; want one")
     return sizes.pop()
 
 

@@ -63,3 +63,19 @@ def test_control_is_not_correct():
     assert out["program_gap"] <= LIMIT
     assert not out["correct"], out["checks"]
     assert out["checks"]["served_gap"]["value"] > LIMIT
+
+
+def test_kv_bytes_reads_the_pages_beside_recurrent_state():
+    """A hybrid stack's pool holds fp32 SSM state beside its KV pages;
+    the K and V pages alone give the bytes of a value."""
+    import jax.numpy as jnp
+
+    from repro.configs import reduced_config
+    from repro.serve.engine import ServeEngine
+
+    from bench import serve as S
+
+    engine = ServeEngine(reduced_config("hymba-1.5b"), None, batch=2,
+                         cache_len=64, eos_id=-1, kv_layout="paged",
+                         cache_dtype=jnp.bfloat16)
+    assert S.kv_bytes(engine) == 2

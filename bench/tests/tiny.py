@@ -1,18 +1,14 @@
 """Tiny stand-ins for the cells' configurations and mixes, for CPU tests:
-the same families and code paths at d_model 64, two layers."""
+the same families and code paths at d_model 64 (each family's `tiny()`)."""
 import copy
 
 from bench import traffic, weights
+from bench.families import family
 
 
 def conf(name: str) -> dict:
     c = weights.load_config(name)
-    out = dict(c, hidden_size=64, num_attention_heads=4,
-               num_key_value_heads=2, num_hidden_layers=2, vocab_size=503,
-               intermediate_size=32 if c.get("num_local_experts") else 96)
-    if c.get("num_local_experts"):
-        out.update(num_local_experts=8, num_experts_per_tok=2)
-    return out
+    return family(c["model_type"]).tiny(c)
 
 
 def mix(name: str, limit: float) -> dict:

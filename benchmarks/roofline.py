@@ -49,7 +49,10 @@ def analytic_bytes_per_device(arch: str, shape_name: str, n_dev: int) -> float:
     shape = SHAPES_BY_NAME[shape_name]
     P = cfg.param_count()
     L, d, T, GB = cfg.num_layers, cfg.d_model, shape.seq_len, shape.global_batch
-    H = max(cfg.num_heads, cfg.n_ssm_heads if cfg.attn_free else cfg.num_heads)
+    # layers whose mixer attends / keeps Mamba2 state (hybrid: both)
+    n_attn = sum(cfg.mixer(i) != "mamba" for i in range(L))
+    n_ssm = sum(cfg.mixer(i) != "attention" for i in range(L))
+    H = max(cfg.num_heads, cfg.num_heads if n_attn else cfg.n_ssm_heads)
     # attention head sharding efficiency: replicated when KVH doesn't divide
     # the 16-way model axis (this is also visible as the FLOPs inflation)
     tp = 16
@@ -60,28 +63,24 @@ def analytic_bytes_per_device(arch: str, shape_name: str, n_dev: int) -> float:
         opt = 28.0 * P / n_dev                       # 7 fp32 quantities r/w
         wts = 3.0 * 2.0 * P / n_dev * 1.0            # bf16 fwd+dgrad+wgrad
         act = ACT_PASSES["train"] * L * tokens_dev * d * 2.0
-        attn_p = 0.0
-        if not cfg.attn_free:
-            attn_p = 5.0 * L * (GB / n_dev) * heads_eff * T * T * 4.0
+        attn_p = 5.0 * n_attn * (GB / n_dev) * heads_eff * T * T * 4.0
         return opt + wts + act + attn_p
     if shape.kind == "prefill":
         wts = 2.0 * P / n_dev
         act = ACT_PASSES["prefill"] * L * tokens_dev * d * 2.0
-        attn_p = 0.0
-        if not cfg.attn_free:
-            attn_p = 1.0 * L * (GB / n_dev) * heads_eff * T * T * 4.0
+        attn_p = 1.0 * n_attn * (GB / n_dev) * heads_eff * T * T * 4.0
         return wts + act + attn_p
     # decode: weights (active experts only) + cache read + small activations
     wts = 2.0 * cfg.active_param_count() / n_dev
     cache = 0.0
-    if not cfg.attn_free:
-        for i in range(L):
-            kind = cfg.layer_kind(i)
-            S = min(cfg.window, T) if kind["attn"] == "local" else T
-            cache += 2 * GB * S * cfg.num_kv_heads * cfg.hd * 2.0
-    if cfg.attn_free or cfg.hybrid:
-        cache += (GB * cfg.n_ssm_heads * cfg.ssm_head_dim * cfg.ssm_state
-                  * 4.0 * 2 * L)
+    for i in range(L):
+        kind = cfg.layer_kind(i)
+        if kind["mixer"] == "mamba":
+            continue
+        S = min(cfg.window, T) if kind["attn"] == "local" else T
+        cache += 2 * GB * S * cfg.num_kv_heads * cfg.hd * 2.0
+    cache += (GB * cfg.n_ssm_heads * cfg.ssm_head_dim * cfg.ssm_state
+              * 4.0 * 2 * n_ssm)
     act = ACT_PASSES["decode"] * L * (GB / n_dev) * d * 2.0
     return wts + cache / n_dev + act
 

@@ -1,4 +1,4 @@
-"""Architecture registry: the 10 assigned archs + reduced smoke variants."""
+"""Architecture registry: the assigned archs + reduced smoke variants."""
 from __future__ import annotations
 
 import dataclasses
@@ -7,6 +7,7 @@ from repro.models.config import ArchConfig, SHAPES, SHAPES_BY_NAME, ShapeCfg
 
 from .hymba_1_5b import CONFIG as HYMBA
 from .granite_moe_3b import CONFIG as GRANITE
+from .granite_4_0_h_small import CONFIG as GRANITE_H
 from .llama4_maverick import CONFIG as LLAMA4
 from .mamba2_2_7b import CONFIG as MAMBA2
 from .whisper_tiny import CONFIG as WHISPER
@@ -18,7 +19,7 @@ from .pixtral_12b import CONFIG as PIXTRAL
 
 REGISTRY: dict[str, ArchConfig] = {c.name: c for c in (
     HYMBA, GRANITE, LLAMA4, MAMBA2, WHISPER, PHI3, QWEN25, GEMMA2, GEMMA3,
-    PIXTRAL)}
+    PIXTRAL, GRANITE_H)}
 
 ARCH_NAMES = tuple(REGISTRY)
 
@@ -54,9 +55,11 @@ def reduced_config(name: str, layers_per_period: int = 1,
         num_heads=n_heads, num_kv_heads=n_kv,
         d_ff=0 if full.family == "ssm" else 128 * width,
         d_ff_dense=128 * width if full.d_ff_dense else 0,
+        d_ff_shared=256 * width if full.d_ff_shared else 0,
         vocab_size=503,  # odd on purpose: catches divisibility assumptions
         window=min(full.window, 8) if full.window else 0,
         ssm_state=16 if full.ssm_state else 0,
+        ssm_heads=0,
         ssm_head_dim=16 if full.ssm_state else 64,
         ssm_chunk=8,
         num_experts=min(full.num_experts, 8),

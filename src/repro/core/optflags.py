@@ -153,18 +153,19 @@ def disagg_enabled() -> bool:
 
 def prefill_bucket_enabled() -> bool:
     """Prompt-length bucketing in the serve prefill path (reads
-    REPRO_PREFILL_BUCKET at call time, default off). When on, attention-
-    only engines pad each prefill's token block up to a powers-of-two-ish
+    REPRO_PREFILL_BUCKET at call time, default off). When on, engines
+    pad each prefill's token block up to a powers-of-two-ish
     bucket length, so mixed --prompt-lens streams reuse a handful of jit
     traces instead of retracing per distinct length (the summary's
     `prefill_compiles` counts distinct traces). Padded rows are masked
     after the fact: their cache positions are forced to -1 (invisible to
     the attention mask, exactly like empty ring entries) and the logits
     are taken at the real last token via `last_index`, so real rows come
-    out of the same causal arithmetic. Engines auto-disable bucketing for
-    layouts where padded writes could touch live state (local-window
-    rings, ssm/hybrid recurrence) — right-padding a recurrence advances
-    it through garbage tokens."""
+    out of the same causal arithmetic; recurrent (ssm/conv) layers give
+    padded rows dt = 0 and take the conv tail at the real length, so
+    padding leaves their state as it stands. Engines auto-disable
+    bucketing where padded writes could touch live state (local-window
+    rings)."""
     return os.environ.get("REPRO_PREFILL_BUCKET", "0") not in (
         "0", "false", "off")
 

@@ -20,6 +20,7 @@ class ArchConfig:
     # attention
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    position_embedding: str = "rope"   # rope | nope (granite-4.0-h: none)
     attn_pattern: tuple[str, ...] = ("global",)   # cycled over layers
     window: int = 4096              # size of "local" sliding windows
     attn_softcap: float = 0.0       # 0 => off (gemma2: 50)
@@ -30,7 +31,12 @@ class ArchConfig:
     experts_per_token: int = 0
     moe_every: int = 1              # MoE FFN every N layers (llama4: 2)
     shared_expert: bool = False
+    d_ff_shared: int = 0            # shared expert's width (0 => d_ff)
     d_ff_dense: int = 0             # FFN width of non-MoE layers (0 => d_ff)
+    # the experts [lo, hi) of the router's `num_experts` whose weights this
+    # layer holds (expert parallelism: the other chips hold the rest);
+    # empty => all of them. The router keeps its full width and top-k.
+    experts_held: tuple[int, int] = ()
     # force the exact dropless dispatch on *every* path (training included);
     # the serving path is dropless regardless via optflags.moe_dropless_serve.
     # Used by parity references: capacity-drop is not decode-exact.
@@ -42,9 +48,16 @@ class ArchConfig:
     ssm_head_dim: int = 64
     ssm_expand: int = 2
     ssm_chunk: int = 256
+    ssm_conv_bias: bool = False
+    ssm_head_d: bool = False        # skip term D per head (H,), not per channel
 
     # structure
     hybrid: bool = False            # hymba: parallel attn ∥ SSM heads per layer
+    # per-layer mixer kinds in sequence, cycled over layers like
+    # attn_pattern: "attention" | "mamba" (granite-4.0-h: nine Mamba2
+    # layers, one attention layer). Empty => every layer's mixer follows
+    # `family` and `hybrid` (see `mixer`).
+    layer_types: tuple[str, ...] = ()
     encoder_layers: int = 0         # >0 => encoder-decoder (whisper)
     frontend_tokens: int = 0        # stub modality frontend sequence length
     frontend_dim: int = 0           # stub frontend embedding dim (0 => d_model)
@@ -69,6 +82,11 @@ class ArchConfig:
     def __post_init__(self):
         if self.family not in ("dense", "moe", "ssm", "hybrid", "audio", "vlm"):
             raise ValueError(f"unknown family {self.family!r}")
+        if not set(self.layer_types) <= {"attention", "mamba"}:
+            raise ValueError(f"unknown layer types {self.layer_types!r}")
+        if self.position_embedding not in ("rope", "nope"):
+            raise ValueError(
+                f"unknown position embedding {self.position_embedding!r}")
 
     @property
     def hd(self) -> int:
@@ -94,12 +112,24 @@ class ArchConfig:
         return self.d_inner // self.ssm_head_dim
 
     @property
+    def held_experts(self) -> tuple[int, int]:
+        """[lo, hi) of the router's experts whose weights are held here."""
+        return tuple(self.experts_held) or (0, self.num_experts)
+
+    @property
+    def n_held_experts(self) -> int:
+        lo, hi = self.held_experts
+        return hi - lo
+
+    @property
     def is_encdec(self) -> bool:
         return self.encoder_layers > 0
 
     @property
-    def attn_free(self) -> bool:
-        return self.family == "ssm"
+    def recurrent(self) -> bool:
+        """Some layer keeps recurrent (ssm/conv) state per sequence."""
+        return any(self.mixer(j) != "attention"
+                   for j in range(self.stack_period))
 
     @property
     def sub_quadratic(self) -> bool:
@@ -109,24 +139,34 @@ class ArchConfig:
         return (all(p == "local" for p in self.attn_pattern)
                 or "local" in self.attn_pattern)
 
+    def mixer(self, i: int) -> str:
+        """Layer i's token mixer: "attention", "mamba" (Mamba2) or
+        "hybrid" (Hymba's attention ∥ Mamba2 heads in one layer)."""
+        if self.layer_types:
+            return self.layer_types[i % len(self.layer_types)]
+        if self.family == "ssm":
+            return "mamba"
+        return "hybrid" if self.hybrid else "attention"
+
     def layer_kind(self, i: int) -> dict:
         """Structural descriptor of layer i (drives block assembly)."""
         attn = self.attn_pattern[i % len(self.attn_pattern)]
         is_moe = (self.num_experts > 0) and (i % self.moe_every == self.moe_every - 1)
-        return {"attn": attn, "moe": is_moe}
+        return {"attn": attn, "moe": is_moe, "mixer": self.mixer(i)}
 
     @property
     def stack_period(self) -> int:
         """Length of the repeating structural pattern (scan superblock)."""
         return int(math.lcm(len(self.attn_pattern),
-                            self.moe_every if self.num_experts else 1))
+                            self.moe_every if self.num_experts else 1,
+                            len(self.layer_types) or 1))
 
     def active_param_count(self) -> int:
         """Parameters touched per token (MoE: top-k experts + shared)."""
         if not self.num_experts:
             return self.param_count()
         total = self.param_count()
-        per_moe_layer = self.num_experts * 3 * self.d_model * self.d_ff
+        per_moe_layer = self.n_held_experts * 3 * self.d_model * self.d_ff
         active_moe = self.experts_per_token * 3 * self.d_model * self.d_ff
         n_moe = sum(1 for i in range(self.num_layers)
                     if self.layer_kind(i)["moe"])
@@ -142,14 +182,14 @@ class ArchConfig:
         ffn_dense = ffn_mats * d * (self.d_ff_dense or self.d_ff)
         for i in range(self.num_layers):
             kind = self.layer_kind(i)
-            p = 0 if self.attn_free else attn
-            if self.family == "ssm" or self.hybrid:
+            p = attn if kind["mixer"] in ("attention", "hybrid") else 0
+            if kind["mixer"] in ("mamba", "hybrid"):
                 din = self.d_inner
                 p += d * (2 * din + 2 * self.ssm_state) + din * d
             if kind["moe"]:
-                p += self.num_experts * 3 * d * self.d_ff
+                p += self.n_held_experts * 3 * d * self.d_ff
                 if self.shared_expert:
-                    p += 3 * d * self.d_ff
+                    p += 3 * d * (self.d_ff_shared or self.d_ff)
                 p += d * self.num_experts
             elif self.family != "ssm":
                 p += ffn_dense

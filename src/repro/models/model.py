@@ -62,37 +62,43 @@ def _init_ffn(rng, cfg: ArchConfig, d_ff: int):
 
 
 def _init_moe(rng, cfg: ArchConfig):
-    d, E, F = cfg.d_model, cfg.num_experts, cfg.d_ff
+    d, E, F = cfg.d_model, cfg.n_held_experts, cfg.d_ff
     ks = jax.random.split(rng, 5)
     p = {
-        "router": _dense(ks[0], d, (d, E)),
+        "router": _dense(ks[0], d, (d, cfg.num_experts)),
         "wg": _dense(ks[1], d, (E, d, F)),
         "wu": _dense(ks[2], d, (E, d, F)),
         "wd": _dense(ks[3], F, (E, F, d)),
     }
     if cfg.shared_expert:
         sk = jax.random.split(ks[4], 3)
-        p |= {"shared_wg": _dense(sk[0], d, (d, F)),
-              "shared_wu": _dense(sk[1], d, (d, F)),
-              "shared_wd": _dense(sk[2], F, (F, d))}
+        Fs = cfg.d_ff_shared or F
+        p |= {"shared_wg": _dense(sk[0], d, (d, Fs)),
+              "shared_wu": _dense(sk[1], d, (d, Fs)),
+              "shared_wd": _dense(sk[2], Fs, (Fs, d))}
     return p
 
 
 def _init_ssm(rng, cfg: ArchConfig):
     d, din, N, H = cfg.d_model, cfg.d_inner, cfg.ssm_state, cfg.n_ssm_heads
     ks = jax.random.split(rng, 3)
-    conv_dim = 2 * din + 2 * N  # x, B, C get conv'd; (z, dt skip it) — we
-    # conv the [x|B|C] concat (width din + 2N) per mamba2
+    # mamba2 convolves the [x|B|C] concat (width din + 2N); z and dt skip it
     conv_dim = din + 2 * N
-    return {
+    p = {
         "in_proj": _dense(ks[0], d, (d, 2 * din + 2 * N + H)),
         "conv_w": jax.random.normal(ks[1], (4, conv_dim)) * 0.1,
         "dt_bias": jnp.zeros((H,)),
         "A_log": jnp.zeros((H,)),
-        "D_skip": jnp.ones((din,)),
         "norm_w": jnp.ones((din,)),
         "out_proj": _dense(ks[2], din, (din, d)),
     }
+    if cfg.ssm_conv_bias:
+        p["conv_b"] = jnp.zeros((conv_dim,))
+    if cfg.ssm_head_d:
+        p["D"] = jnp.ones((H,))
+    else:
+        p["D_skip"] = jnp.ones((din,))
+    return p
 
 
 def _norm_p(cfg):
@@ -109,11 +115,14 @@ def init_layer(rng, cfg: ArchConfig, meta: dict, cross: bool = False):
         p["ssm"] = _init_ssm(ks[0], cfg)
         p.pop("norm2")
         return p
-    if cfg.hybrid:
+    mixer = meta.get("mixer", "attention")
+    if mixer == "hybrid":
         p["attn"] = _init_attn(ks[0], cfg)
         p["ssm"] = _init_ssm(ks[1], cfg)
         p["attn_norm"] = {"w": jnp.ones((cfg.d_model,))}
         p["ssm_norm"] = {"w": jnp.ones((cfg.d_model,))}
+    elif mixer == "mamba":
+        p["ssm"] = _init_ssm(ks[1], cfg)
     else:
         p["attn"] = _init_attn(ks[0], cfg)
     if cross:
@@ -204,7 +213,7 @@ def init_cache(cfg: ArchConfig, batch: int, seq_len: int,
     def layer_cache(j):
         meta = cfg.layer_kind(j)
         c = {}
-        if cfg.family != "ssm":
+        if meta["mixer"] != "mamba":
             S = min(cfg.window, seq_len) if meta["attn"] == "local" else seq_len
             if paged is not None and not (meta["attn"] == "local"
                                           and cfg.window
@@ -222,7 +231,7 @@ def init_cache(cfg: ArchConfig, batch: int, seq_len: int,
                     k=mk((n_super, batch, S, kvh, cfg.hd)),
                     v=mk((n_super, batch, S, kvh, cfg.hd)),
                     positions=mk((n_super, batch, S), jnp.int32, -1))
-        if cfg.family == "ssm" or cfg.hybrid:
+        if meta["mixer"] != "attention":
             c["ssm"] = (
                 mk((n_super, batch, cfg.n_ssm_heads, cfg.ssm_head_dim,
                     cfg.ssm_state), jnp.float32),
@@ -245,33 +254,39 @@ def init_cache(cfg: ArchConfig, batch: int, seq_len: int,
 # ---------------------------------------------------------------------------
 
 def _sublayer(x, p, cfg, meta, positions, cache, pos, encoder_out,
-              prefix_len: int = 0, decode_multi: bool = False, layer=None):
+              prefix_len: int = 0, decode_multi: bool = False, layer=None,
+              n_valid=None):
     """One transformer layer. Returns (x, new_cache).
 
     `cache` holds the whole stack's leaves for this sublayer (leading
     superblock axis) and `layer` is the superblock's index: every write
     lands at [layer, ...] of the stacked leaves, and leaves the layer does
-    not write pass through."""
+    not write pass through. `n_valid` (traced) counts the real rows of a
+    right-padded prefill block: padding leaves recurrent state as it is."""
     new_cache: dict[str, Any] = dict(cache) if cache is not None else {}
     h = L.norm_apply(x, p["norm1"], cfg.norm, cfg.norm_eps)
+    mixer = meta.get("mixer", "attention")
     if cfg.family == "ssm":
-        mix, new_cache["ssm"] = _ssm_mix(h, p["ssm"], cfg, cache, layer)
+        mix, new_cache["ssm"] = _ssm_mix(h, p["ssm"], cfg, cache, layer,
+                                         n_valid)
         return x + mix.astype(x.dtype), (new_cache if cache is not None else None)
     kv_cache = cache.get("kv") if cache else None
-    if cfg.hybrid:
-        a, kv = L.attention_block(h, p["attn"], cfg, meta, positions,
-                                  cache=kv_cache, pos=pos,
-                                  prefix_len=prefix_len,
-                                  decode_multi=decode_multi, layer=layer)
-        s, new_cache["ssm"] = _ssm_mix(h, p["ssm"], cfg, cache, layer)
+    attend = functools.partial(
+        L.attention_block, cfg=cfg, meta=meta, positions=positions,
+        cache=kv_cache, pos=pos, rope=cfg.position_embedding == "rope",
+        prefix_len=prefix_len, decode_multi=decode_multi, layer=layer)
+    if mixer == "hybrid":
+        a, kv = attend(h, p["attn"])
+        s, new_cache["ssm"] = _ssm_mix(h, p["ssm"], cfg, cache, layer,
+                                       n_valid)
         mix = 0.5 * (L.rmsnorm(a, p["attn_norm"]["w"], cfg.norm_eps)
                      + L.rmsnorm(s, p["ssm_norm"]["w"], cfg.norm_eps))
+    elif mixer == "mamba":
+        mix, new_cache["ssm"] = _ssm_mix(h, p["ssm"], cfg, cache, layer,
+                                         n_valid)
     else:
-        mix, kv = L.attention_block(h, p["attn"], cfg, meta, positions,
-                                    cache=kv_cache, pos=pos,
-                                    prefix_len=prefix_len,
-                                    decode_multi=decode_multi, layer=layer)
-    if cache is not None:
+        mix, kv = attend(h, p["attn"])
+    if cache is not None and mixer != "mamba":
         new_cache["kv"] = kv
     x = x + mix.astype(x.dtype)
     if cfg.is_encdec and encoder_out is not None:
@@ -298,13 +313,14 @@ def _sublayer(x, p, cfg, meta, positions, cache, pos, encoder_out,
     return x + f.astype(x.dtype), (new_cache if cache is not None else aux)
 
 
-def _ssm_mix(h, p, cfg, cache, layer):
+def _ssm_mix(h, p, cfg, cache, layer, n_valid=None):
     """Mamba2 mixer on superblock `layer` of a stacked ssm/conv state.
     Returns (mix, the updated stacked state, or None without a cache)."""
     ssm = cache.get("ssm") if cache else None
     if ssm is None:
-        return mamba2_block(h, p, cfg)[0], None
-    mix, st = mamba2_block(h, p, cfg, *(L.at_layer(a, layer) for a in ssm))
+        return mamba2_block(h, p, cfg, n_valid=n_valid)[0], None
+    mix, st = mamba2_block(h, p, cfg, *(L.at_layer(a, layer) for a in ssm),
+                           n_valid=n_valid)
     return mix, tuple(L.set_layer(a, new, layer) for a, new in zip(ssm, st))
 
 
@@ -347,7 +363,8 @@ def encode(params, cfg: ArchConfig, frontend_embeds):
 
 def forward(params, cfg: ArchConfig, tokens, *, positions=None, cache=None,
             pos=None, frontend_embeds=None, last_only: bool = False,
-            last_index=None, prefix_len: int = 0, decode_multi: bool = False):
+            last_index=None, prefix_len: int = 0, decode_multi: bool = False,
+            valid_len=None):
     """Token ids (B, T) → logits. Returns (logits, new_cache, aux).
 
     `cache`/`pos` engage the decode path; `pos` is a (B,) int32 vector of
@@ -364,10 +381,12 @@ def forward(params, cfg: ArchConfig, tokens, *, positions=None, cache=None,
     (traced, used with `last_only`) selects WHICH row feeds the lm_head
     instead of the static -1: bucketed prefill (serve prompt-length
     bucketing) right-pads the token block, so the real prompt's logits
-    live at row `last_index`, not the padded block's end.
+    live at row `last_index`, not the padded block's end. `valid_len`
+    (traced) is such a prefill's real absolute length: recurrent layers
+    leave their state as it stands at that position (model._sublayer).
     """
     B, T = tokens.shape
-    if decode_multi and (cfg.family == "ssm" or cfg.hybrid):
+    if decode_multi and cfg.recurrent:
         # a rejected draft would leave the recurrent state advanced past
         # the rollback point; attention caches roll back by position,
         # ssm states cannot — the serve engine gates spec decoding off
@@ -399,6 +418,7 @@ def forward(params, cfg: ArchConfig, tokens, *, positions=None, cache=None,
 
     period = cfg.stack_period
     n_super = jax.tree.leaves(params["layers"])[0].shape[0]
+    n_valid = None if valid_len is None else valid_len - prefix_len
 
     def superblock(carry, xs):
         # the cache rides in the carry, not in xs/ys: each layer writes its
@@ -424,7 +444,8 @@ def forward(params, cfg: ArchConfig, tokens, *, positions=None, cache=None,
             c_j = None if cache is None else cache[j]
             x, extra = _sublayer(x, p_sb[j], cfg, cfg.layer_kind(j),
                                  positions, c_j, pos, encoder_out,
-                                 prefix_len, decode_multi, layer=i)
+                                 prefix_len, decode_multi, layer=i,
+                                 n_valid=n_valid)
             if cache is not None:
                 new_caches.append(extra)
             elif isinstance(extra, dict):   # moe aux losses

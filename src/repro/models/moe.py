@@ -18,6 +18,11 @@ Two dispatches share one router:
   (`model._sublayer` under a cache, optflag ``moe_dropless_serve``) or
   arch-wide via ``cfg.moe_dropless``.
 
+Both take the layer's held experts (`cfg.experts_held`): the router scores
+all `num_experts` at the published top-k, and the layer computes the part
+of the result that its own experts give — what one chip of an
+expert-parallel deployment computes before the exchange.
+
 Every contraction in both paths is a GEMM under the SA precision contract,
 and all shapes are static — no ragged collectives, dry-run friendly.
 """
@@ -55,6 +60,14 @@ def router(x, w_router, k: int):
     return combine, aux
 
 
+def held_combine(x, p, cfg):
+    """Router over all `cfg.num_experts`, then the combine weights of the
+    held experts only: (B, T, n_held) + aux losses."""
+    combine, aux = router(x, p["router"], cfg.experts_per_token)
+    lo, hi = cfg.held_experts
+    return combine[..., lo:hi], aux
+
+
 def capacity(T: int, E: int, k: int, factor: float = 1.25) -> int:
     return max(1, min(T, math.ceil(T * k * factor / E)))
 
@@ -78,8 +91,8 @@ def moe_ffn_dropless(x, p, cfg, act: str = "silu"):
     """
     from jax import lax
     B, T, D = x.shape
-    E, k = cfg.num_experts, cfg.experts_per_token
-    combine, aux = router(x, p["router"], k)              # (B, T, E)
+    combine, aux = held_combine(x, p, cfg)                # (B, T, E held)
+    E = combine.shape[-1]
     tp = max(S_.axis_count("model"), 1)
     ep_axis = "model" if E % tp == 0 else None
     Tc = min(T, DROPLESS_CHUNK_T)
@@ -116,9 +129,10 @@ def moe_ffn(x, p, cfg, act: str = "silu", capacity_factor: float = 1.25,
     if dropless:
         return moe_ffn_dropless(x, p, cfg, act)
     B, T, D = x.shape
-    E, k = cfg.num_experts, cfg.experts_per_token
-    C = capacity(T, E, k, capacity_factor)
-    combine, aux = router(x, p["router"], k)              # (B, T, E)
+    # tokens per expert as the whole router spreads them
+    C = capacity(T, cfg.num_experts, cfg.experts_per_token, capacity_factor)
+    combine, aux = held_combine(x, p, cfg)                # (B, T, E held)
+    E = combine.shape[-1]
 
     tp = max(S_.axis_count("model"), 1)
     wg, wu, wd = p["wg"], p["wu"], p["wd"]
@@ -142,7 +156,7 @@ def moe_ffn(x, p, cfg, act: str = "silu", capacity_factor: float = 1.25,
     # stack per device (observed: 160 GiB/dev on llama4 before this).
     ep_axis = "model" if E % tp == 0 else None
     xe = S_.constrain(xe, "batch", ep_axis, None, None)
-    if ep_axis and E != cfg.num_experts:   # padded weights: pin EP layout
+    if ep_axis and E != cfg.n_held_experts:   # padded: pin EP layout
         wg = S_.constrain(wg, ep_axis, None, None)
         wu = S_.constrain(wu, ep_axis, None, None)
         wd = S_.constrain(wd, ep_axis, None, None)

@@ -107,22 +107,34 @@ def ssd_decode_step(state, x_t, dt_t, A, B_t, C_t):
     return state, y
 
 
-def _causal_conv(x, w, cache=None):
-    """Depthwise causal conv1d. x: (B, T, D); w: (KW, D). Returns (y, tail)
-    where tail is the last KW-1 inputs (decode cache)."""
+def _causal_conv(x, w, cache=None, b=None, n_valid=None):
+    """Depthwise causal conv1d. x: (B, T, D); w: (KW, D); optional bias
+    b: (D,). Returns (y, tail) where tail is the last KW-1 inputs (decode
+    cache) — the last KW-1 before row `n_valid` where rows from there on
+    are padding."""
     KW = w.shape[0]
+    T = x.shape[1]
     if cache is not None:
         xp = jnp.concatenate([cache, x], axis=1)
     else:
         xp = jnp.pad(x, ((0, 0), (KW - 1, 0), (0, 0)))
-    y = sum(xp[:, i:i + x.shape[1]] * w[i] for i in range(KW))
-    tail = xp[:, -(KW - 1):] if KW > 1 else None
+    y = sum(xp[:, i:i + T] * w[i] for i in range(KW))
+    if b is not None:
+        y = y + b
+    tail = None
+    if KW > 1:
+        # input row t sits at xp row t + KW - 1
+        tail = (xp[:, -(KW - 1):] if n_valid is None else
+                lax.dynamic_slice_in_dim(xp, n_valid, KW - 1, axis=1))
     return jax.nn.silu(y), tail
 
 
-def mamba2_block(x, p, cfg, state=None, conv_cache=None):
+def mamba2_block(x, p, cfg, state=None, conv_cache=None, n_valid=None):
     """Full Mamba2 mixer. x: (B, T, D). If `state` is given and T == 1 runs
-    the recurrent decode path. Returns (y, (state, conv_cache))."""
+    the recurrent decode path. `n_valid` (traced) marks rows from there on
+    as padding (bucketed prefill): their dt is 0, which leaves the state as
+    it is (decay 1, no input), and the conv tail is taken before them.
+    Returns (y, (state, conv_cache))."""
     B, T, D = x.shape
     H, P, N = cfg.n_ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
     din = cfg.d_inner
@@ -130,10 +142,13 @@ def mamba2_block(x, p, cfg, state=None, conv_cache=None):
     z, xin, B_, C_, dt = jnp.split(
         zxbcdt, [din, 2 * din, 2 * din + N, 2 * din + 2 * N], axis=-1)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])  # (B, T, H)
+    if n_valid is not None:
+        dt = jnp.where((jnp.arange(T) < n_valid)[None, :, None], dt, 0.0)
     A = -jnp.exp(p["A_log"].astype(jnp.float32))                 # (H,)
 
     conv_in = jnp.concatenate([xin, B_, C_], axis=-1)
-    conv_out, conv_tail = _causal_conv(conv_in, p["conv_w"], conv_cache)
+    conv_out, conv_tail = _causal_conv(conv_in, p["conv_w"], conv_cache,
+                                       p.get("conv_b"), n_valid)
     xin, B_, C_ = jnp.split(conv_out, [din, din + N], axis=-1)
     xh = xin.reshape(B, T, H, P)
 
@@ -145,7 +160,10 @@ def mamba2_block(x, p, cfg, state=None, conv_cache=None):
     else:
         y, new_state = ssd_chunked(xh, dt, A, B_, C_, cfg.ssm_chunk,
                                    initial_state=state)
-    y = y.reshape(B, T, din) + xin * p["D_skip"]
+    if "D" in p:        # one skip weight per head (Mamba2 as published)
+        y = (y + xh * p["D"][:, None]).reshape(B, T, din)
+    else:
+        y = y.reshape(B, T, din) + xin * p["D_skip"]
     y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
                 p["norm_w"], cfg.norm_eps)
     out = sa_dot(y.reshape(B * T, din), p["out_proj"]).reshape(B, T, D)

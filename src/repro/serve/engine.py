@@ -17,10 +17,8 @@ Architecture (DESIGN.md §Serving):
 * **Single-slot prefill** — an admission prefills (1, T_prompt) and the
   resulting cache fragment is dynamic-update-sliced into batch row `slot`
   of every cache leaf (they all carry batch at axis 1 — see
-  model.init_cache). Prefill retraces per distinct prompt length; drivers
-  should quantize prompt lengths to a small set. Right-padding prompts
-  instead would corrupt SSM/hybrid states (padded tokens update the
-  recurrence), so exact-length prefill is the correctness-first default.
+  model.init_cache). Prefill retraces per distinct prompt length unless
+  prompt-length bucketing (below) is on.
 * **Paged KV (default)** — under ``REPRO_KV=paged`` (the default; ``ring``
   is the A/B fallback) `serve()` replaces the per-slot fixed rings with a
   global page pool + per-slot block tables (DESIGN.md §5): the scheduler's
@@ -41,11 +39,13 @@ Architecture (DESIGN.md §Serving):
   program, so the split cannot change tokens: ``REPRO_DISAGG=1|0`` is
   pinned token-identical on the stream digest (CI serve-smoke).
 * **Prompt-length bucketing** (``REPRO_PREFILL_BUCKET=1`` /
-  ``bucket_prompts=True``) — attention-only engines pad each prefill
-  suffix up to a powers-of-two-ish bucket so mixed prompt-length streams
-  share O(log) jit traces instead of one per distinct length; padded rows
-  get positions -1 (invisible to the attention mask, like empty ring
-  entries) and the first token reads the real last row via `last_index`.
+  ``bucket_prompts=True``) — engines without local-window rings pad each
+  prefill suffix up to a powers-of-two-ish bucket so mixed prompt-length
+  streams share O(log) jit traces instead of one per distinct length;
+  padded rows get positions -1 (invisible to the attention mask, like
+  empty ring entries), leave recurrent (ssm/conv) state as it stands at
+  the real length (dt = 0 there; the conv tail is taken before them), and
+  the first token reads the real last row via `last_index`.
   The summary's `prefill_compiles` counts distinct prefill traces either
   way.
 """
@@ -223,7 +223,7 @@ class ServeEngine:
         return (optflags.prefix_cache_enabled()
                 and self.kv_layout == "paged"
                 and self._frag_floor == 1
-                and self.cfg.family != "ssm" and not self.cfg.hybrid)
+                and not self.cfg.recurrent)
 
     def disagg_on(self) -> bool:
         """The two-pool split is sound exactly where prefix sharing is:
@@ -237,18 +237,19 @@ class ServeEngine:
               else optflags.disagg_enabled())
         return (on and self.kv_layout == "paged"
                 and self._frag_floor == 1
-                and self.cfg.family != "ssm" and not self.cfg.hybrid)
+                and not self.cfg.recurrent)
 
     def bucketing_on(self) -> bool:
-        """Prompt-length bucketing is sound only for pure-attention
-        stacks: right-padding advances ssm/hybrid recurrent state through
-        garbage tokens, and local-window ring writes past the real length
-        could wrap onto live rows. Opt-in (REPRO_PREFILL_BUCKET /
+        """Prompt-length bucketing is sound wherever padded rows stay
+        inert: attention masks their positions, and recurrent (ssm/conv)
+        layers take dt = 0 and the conv tail at the real length
+        (train.step.make_bucketed_prefill_step). Local-window ring writes
+        past the real length could wrap onto live rows, so local-window
+        stacks stay exact-length. Opt-in (REPRO_PREFILL_BUCKET /
         constructor `bucket_prompts`)."""
         on = (self._bucket_arg if self._bucket_arg is not None
               else optflags.prefill_bucket_enabled())
-        return (on and self._frag_floor == 1
-                and self.cfg.family != "ssm" and not self.cfg.hybrid)
+        return on and self._frag_floor == 1
 
     def spec_decoding_on(self) -> bool:
         """Self-speculative decoding is armed (spec_k >= 1 and the
@@ -266,7 +267,7 @@ class ServeEngine:
                           else None))
         return (optflags.spec_decode_enabled() and self.spec_k >= 1
                 and n_super >= 2
-                and self.cfg.family != "ssm" and not self.cfg.hybrid
+                and not self.cfg.recurrent
                 and (min_ring is None or self.spec_k + 1 <= min_ring))
 
     def _fingerprint(self) -> str:
@@ -882,6 +883,9 @@ class ServeEngine:
         rec.reset(now)
         span = rec.span
         cache = self.new_pool() if paged else self.new_cache()
+        # one slot's recurrent state: ssm and conv leaves of every layer
+        state_bytes = sum(a.size * a.dtype.itemsize for c in cache
+                          for a in c.get("ssm", ())) // B
         tok = jnp.zeros((B,), jnp.int32)
         pos = jnp.zeros((B,), jnp.int32)
         disagg = self.disagg_on()
@@ -1076,6 +1080,10 @@ class ServeEngine:
                         accs_np = np.asarray(accs)
             rec.count("decode_steps", chunk_steps)
             rec.count("decode_slot_steps", chunk_steps * B)
+            if state_bytes:
+                # each step reads and writes every live slot's state
+                rec.count("decode_state_bytes",
+                          2 * state_bytes * len(live) * chunk_steps)
             before = sum(r.n_generated for r in live)
             with span("observe"):
                 if spec:

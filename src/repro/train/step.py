@@ -112,19 +112,23 @@ def make_bucketed_prefill_step(cfg: ArchConfig, prefix_len: int = 0):
       exactly like an empty ring entry, and overwritten in place once the
       request decodes past it. Real rows never see the padded ones
       (causal masking), so their K/V and the selected logits row come out
-      of the same arithmetic as an exact-length prefill.
+      of the same arithmetic as an exact-length prefill;
+    * recurrent layers (ssm, hybrid, granite-4.0-h's Mamba2 layers) read
+      `valid_len` too: padded rows get dt = 0, which leaves the SSM state
+      as it stands at the real length (decay 1, no input), and the conv
+      tail is the last inputs before it (models/ssm.mamba2_block).
 
-    Only sound for attention-only stacks: right-padding would advance
-    ssm/hybrid recurrent state through garbage tokens, and local-window
-    ring writes past the real length could wrap onto live rows — the
-    engine gates bucketing off for those (ServeEngine.bucketing_on)."""
+    Local-window ring writes past the real length could wrap onto live
+    rows, so the engine gates bucketing off for local-window stacks
+    (ServeEngine.bucketing_on)."""
     from repro.models.layers import KVCache
 
     def prefill(params, tokens, cache, last_idx, valid_len, frontend=None):
         logits, cache, _ = M.forward(params, cfg, tokens, cache=cache,
                                      frontend_embeds=frontend,
                                      last_only=True, last_index=last_idx,
-                                     prefix_len=prefix_len)
+                                     prefix_len=prefix_len,
+                                     valid_len=valid_len)
 
         def mask(leaf):
             if not isinstance(leaf, KVCache):

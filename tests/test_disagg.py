@@ -206,6 +206,25 @@ def test_bucketed_serve_identical_fewer_compiles(setup):
     assert on["prefill_compiles"] < off["prefill_compiles"]
 
 
+@pytest.mark.parametrize("arch", ["mamba2-2.7b", "granite-4.0-h-small"])
+def test_bucketed_serve_identical_for_recurrent_stacks(arch):
+    """Stacks with recurrent state bucket too: padded rows leave the SSM
+    state and the conv tail as they stand at the real prompt length, so
+    token streams match exact-length prefill (Mamba2 on its rings,
+    granite-4.0-h's one attention layer in ten on pages)."""
+    cfg = _cfg(arch)
+    with use_policy(FP32):
+        params = M.init_params(jax.random.key(2), cfg)
+    prompts = _prompts(cfg, [5, 6, 7, 9, 13], seed=9)
+    budgets = [6, 5, 6, 4, 6]
+    on_sched, on = _serve(cfg, params, prompts, budgets,
+                          bucket_prompts=True)
+    off_sched, off = _serve(cfg, params, prompts, budgets,
+                            bucket_prompts=False)
+    assert _tokens_by_rid(on_sched) == _tokens_by_rid(off_sched)
+    assert on["prefill_compiles"] < off["prefill_compiles"]
+
+
 def test_disagg_composes_with_bucketing(setup):
     """Both knobs on at once still reproduce the plain engine's streams —
     the staged fragment is bucket-padded, overflow pages land in the trash

@@ -211,3 +211,65 @@ def test_admission_scatters_into_the_pool_in_place(one_chip, monkeypatch):
     assert _pool_shaped(compiled.as_text().splitlines(), k, "copy") == []
     layer_bytes = k.size // k.shape[0] * jnp.dtype(k.dtype).itemsize
     assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+
+
+@pytest.mark.parametrize("program", ["decode_chunk", "prefill_8192"])
+def test_granite_h_longdoc_fits_one_chip(one_chip, monkeypatch, program):
+    """The `granite-h-10l-longdoc` cell's programs at its serving shapes:
+    granite-4.0-h-small cut to one period of ten layers and 9 of 72 experts,
+    bf16 weights, 32 slots over an fp32 pool of 17,409 pages of 16
+    (max_seq_len 8704: every slot at its longest), with the recurrent state
+    of nine Mamba2 layers beside it. The 8-step decode chunk (pool donated)
+    and the largest prefill bucket (8192 tokens, the flash kernel on, as on
+    the chip) each fit below 15 GB of the chip's 16 GiB together with what
+    stays on the device while they run: weights and pool (decode: 8.35 GB
+    of arguments and 0.09 GB of temporaries; prefill: 4.94 + 5.55 GB
+    beside the 3.52 GB pool)."""
+    import dataclasses
+
+    from jax import lax
+
+    from repro.configs import get_config
+    from repro.core import optflags
+    from repro.kernels import ops
+    from repro.models import model as M
+    from repro.train.step import make_bucketed_prefill_step, make_serve_step
+
+    monkeypatch.setattr(ops, "INTERPRET", False)
+    monkeypatch.setitem(optflags.FLAGS, "pallas_attention", True)
+    cfg = dataclasses.replace(get_config("granite-4.0-h-small"),
+                              num_layers=10, experts_held=(0, 9))
+    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip)
+    params = jax.tree.map(place, M.abstract_params(cfg, jnp.bfloat16))
+    pool = jax.tree.map(place, M.init_cache(
+        cfg, 32, 8704, dtype=jnp.float32, abstract=True, paged=(17409, 16)))
+    nbytes = lambda tree: sum(a.size * jnp.dtype(a.dtype).itemsize
+                              for a in jax.tree.leaves(tree))
+    i32 = lambda *shape: place(jax.ShapeDtypeStruct(shape, jnp.int32))
+    if program == "decode_chunk":
+        serve_step = make_serve_step(cfg)
+
+        def chunk(params, tok, cache, pos):
+            def body(carry, _):
+                tok, cache, pos = carry
+                logits, cache = serve_step(params, tok[:, None], cache, pos)
+                nxt = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
+                return (nxt, cache, pos + 1), nxt
+
+            return lax.scan(body, (tok, cache, pos), length=8)
+
+        compiled = jax.jit(chunk, donate_argnums=(2,)).lower(
+            params, i32(32), pool, i32(32)).compile()
+        beside = 0                       # weights and pool are arguments
+    else:
+        frag = jax.tree.map(place, M.init_cache(
+            cfg, 1, 8192, dtype=jnp.float32, abstract=True))
+        compiled = jax.jit(make_bucketed_prefill_step(cfg),
+                           donate_argnums=(2,)).lower(
+            params, i32(1, 8192), frag, i32(), i32()).compile()
+        beside = nbytes(pool)
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes + beside
+    assert used < 15e9, used
